@@ -13,7 +13,7 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from repro.core import distributed_cluster  # noqa: E402
+from repro.core import distributed_cluster, sites_mesh  # noqa: E402
 from repro.core.metrics import outlier_scores  # noqa: E402
 from repro.data.synthetic import gauss, partition  # noqa: E402
 
@@ -25,7 +25,7 @@ def main():
     parts, gids = partition(x, s, "random", seed=3, outlier_ids=out_ids)
     xs = jnp.asarray(np.stack(parts))
 
-    mesh = jax.make_mesh((s,), ("sites",))
+    mesh = sites_mesh(s)
     res = distributed_cluster(xs, jax.random.key(0), mesh, k=16, t=320)
 
     conc = np.concatenate(gids)

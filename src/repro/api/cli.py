@@ -49,10 +49,12 @@ import argparse
 import json
 import sys
 import time
+import tomllib
 from pathlib import Path
 
 import numpy as np
 
+from repro import compile_cache
 from repro.api.config import PipelineConfig
 from repro.api.session import Session
 
@@ -64,12 +66,6 @@ def load_config_file(path) -> tuple[PipelineConfig, dict]:
     path = Path(path)
     text = path.read_text()
     if path.suffix.lower() == ".toml":
-        try:
-            import tomllib
-        except ImportError:  # py3.10: tomllib landed in 3.11
-            raise SystemExit(
-                f"{path}: TOML configs need Python >= 3.11 (tomllib); "
-                f"convert to JSON or upgrade")
         raw = tomllib.loads(text)
     else:
         raw = json.loads(text)
@@ -472,6 +468,7 @@ def main(argv=None) -> None:
     p_tr.set_defaults(fn=cmd_trace)
 
     args = ap.parse_args(argv)
+    compile_cache.enable()
     args.fn(args)
 
 

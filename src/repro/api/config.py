@@ -428,12 +428,15 @@ def _kernels_from(d) -> Optional[KernelPolicy]:
         return d
     if isinstance(d, str):
         return KernelPolicy(backend=d)
-    if not isinstance(d, dict) or set(d) - {"backend", "block_n", "autotune"}:
+    fields = {"backend", "block_n", "autotune", "interpret"}
+    if not isinstance(d, dict) or set(d) - fields:
         raise ValueError(f"kernels must be a backend name in {BACKENDS} or a "
-                         f"{{backend, block_n, autotune}} dict, got {d!r}")
+                         f"{{backend, block_n, autotune, interpret}} dict, "
+                         f"got {d!r}")
     return KernelPolicy(backend=d.get("backend", "auto"),
                         block_n=d.get("block_n"),
-                        autotune=bool(d.get("autotune", False)))
+                        autotune=bool(d.get("autotune", False)),
+                        interpret=bool(d.get("interpret", False)))
 
 
 def pipeline_config(

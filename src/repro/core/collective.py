@@ -8,8 +8,6 @@ every site.  The one-shot path (``repro.core.distributed``) and the sharded
 streaming path (``repro.stream.sharded``) both follow it; this module holds
 the plumbing they would otherwise duplicate:
 
-* ``shard_map``          — version-compat wrapper (``jax.shard_map`` moved
-                           out of ``jax.experimental`` only in newer jax);
 * ``sites_mesh``         — the canonical 1-D mesh over ``sites``;
 * ``gather_sites``       — all_gather a pytree over the axis and collapse
                            the site dim, i.e. "send every site's summary to
@@ -28,26 +26,19 @@ import math
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-
-
-def shard_map(fn, mesh: Mesh, *, in_specs, out_specs):
-    """``jax.shard_map`` where available, ``jax.experimental.shard_map``
-    otherwise (the public alias only exists in newer jax releases)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        # the experimental version predates replication rules for while-loops
-        # (which every k-means inner loop is) — disable the check there.
-        from jax.experimental.shard_map import shard_map as esm
-        return esm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 
 def sites_mesh(n_sites: int | None = None, *, axis: str = "sites") -> Mesh:
-    """1-D mesh over ``axis``: one site per device (default: all devices)."""
+    """1-D mesh over ``axis``: one site per device, on the first ``n_sites``
+    devices (default: all devices).
+
+    The axis is ``Auto``: the replicated coordinator result is read back
+    with a plain ``a[0]`` outside the ``shard_map``, which an ``Explicit``
+    axis (``jax.make_mesh``'s default) refuses to resolve."""
     n = n_sites or len(jax.devices())
-    return jax.make_mesh((n,), (axis,))
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,),
+                         devices=jax.devices()[:n])
 
 
 def gather_sites(tree, axis: str = "sites"):
@@ -72,7 +63,10 @@ def replicated_coordinator(per_site, mesh: Mesh, *, axis: str = "sites",
     arguments are replicated.  ``per_site`` must return a pytree of arrays
     that is *identical on every site* (the coordinator result after a
     ``gather_sites``); the wrapper stacks them over sites and returns site
-    0's copy, so callers get the coordinator view directly.
+    0's copy, on site 0's device, so callers get the coordinator view
+    directly.  It must not stay laid out across the mesh: the serving
+    path feeds it to single-device programs, and a Pallas kernel cannot
+    be partitioned over a mesh.
 
     The returned callable is jit-wrapped around one stable closure per
     argument count, so repeated invocations (e.g. every streaming refresh)
@@ -92,13 +86,23 @@ def replicated_coordinator(per_site, mesh: Mesh, *, axis: str = "sites",
         if fn is None:
             in_specs = tuple(P(axis) if i < n_sharded else P()
                              for i in range(len(args)))
-            fn = jax.jit(shard_map(wrapped, mesh,
-                                   in_specs=in_specs, out_specs=P(axis)))
+            # check_vma=False: the per-site program calls Pallas kernels,
+            # whose outputs carry no varying-axes annotation, and the
+            # result is replicated by construction (it follows the gather)
+            fn = jax.jit(jax.shard_map(wrapped, mesh=mesh,
+                                       in_specs=in_specs, out_specs=P(axis),
+                                       check_vma=False))
             programs[len(args)] = fn
-        out = fn(*args)
-        return jax.tree_util.tree_map(lambda a: a[0], out)
+        return jax.tree_util.tree_map(_site0, fn(*args))
 
     return call
+
+
+def _site0(a):
+    """Site 0's block of a site-stacked array, as a one-device array."""
+    shard = next(s for s in a.addressable_shards
+                 if s.index[0].start in (None, 0))
+    return shard.data[0]
 
 
 def payload_bytes(tree) -> int:

@@ -15,6 +15,7 @@ cumulative weight stays <= t.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -24,6 +25,11 @@ from repro.core.kmeans_pp import kmeanspp_seed
 from repro.kernels.dispatch import KernelPolicy, resolve_policy
 from repro.kernels.lloyd.ops import accumulate_by_assignment, lloyd_step
 from repro.kernels.pdist.ops import min_argmin
+
+
+def seed_trials(k: int) -> int:
+    """Candidates per greedy k-means++ step: 2 + ln k, the usual choice."""
+    return 2 + int(math.log(k))
 
 
 class OutlierClustering(NamedTuple):
@@ -93,7 +99,10 @@ def _kmeans_minus_minus(
     policy: KernelPolicy,
 ) -> OutlierClustering:
     w = weights.astype(jnp.float32) * valid
-    seed_idx, _ = kmeanspp_seed(points, w, key, budget=k, metric=metric)
+    # greedy seeding: plain D^2 draws park centers on far outliers in about
+    # a quarter of seeds, a local optimum the Lloyd loop never leaves
+    seed_idx, _ = kmeanspp_seed(points, w, key, budget=k, metric=metric,
+                                trials=seed_trials(k))
     centers0 = points[seed_idx]
     return _lloyd_outlier_loop(points, w, valid, centers0, k=k, t=t,
                                iters=iters, metric=metric, policy=policy)

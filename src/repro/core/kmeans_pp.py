@@ -8,6 +8,15 @@ Used in three roles:
   * seeding inside k-means|| post-processing.
 
 p = 2 for (k,t)-means, p = 1 for (k,t)-median.
+
+``trials > 1`` is greedy k-means++ (Arthur & Vassilvitskii 2007, §6; the
+scikit-learn default): each step draws ``trials`` candidates by D^p and
+keeps the one that lowers the weighted potential most.  Under outliers
+this matters: a far outlier is the likeliest single D^p draw once most
+clusters are covered, but it lowers the potential by its own weight only,
+while a point of an uncovered cluster lowers it by that cluster's whole
+mass.  The coordinator's k-means-- seeds this way; the k-means++ baseline
+summary keeps the paper's plain seeding (``trials=1``).
 """
 from __future__ import annotations
 
@@ -21,6 +30,7 @@ import jax.numpy as jnp
 from repro.core.summary import Summary
 from repro.kernels.dispatch import KernelPolicy, resolve_policy
 from repro.kernels.pdist.ops import min_argmin
+from repro.kernels.pdist.ref import F32
 
 
 def _dist_to(x, c, metric):
@@ -29,12 +39,12 @@ def _dist_to(x, c, metric):
     if metric == "cosine":
         xn = x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), 1e-30)
         cn = c / jnp.maximum(jnp.linalg.norm(c), 1e-30)
-        return jnp.clip(1.0 - xn @ cn, 0.0, 2.0)
+        return jnp.clip(1.0 - jnp.matmul(xn, cn, precision=F32), 0.0, 2.0)
     sq = ((x - c[None, :]) ** 2).sum(-1)
     return sq if metric == "l2sq" else jnp.sqrt(sq)
 
 
-@functools.partial(jax.jit, static_argnames=("budget", "metric"))
+@functools.partial(jax.jit, static_argnames=("budget", "metric", "trials"))
 def kmeanspp_seed(
     x: jnp.ndarray,
     w: jnp.ndarray,
@@ -42,8 +52,10 @@ def kmeanspp_seed(
     *,
     budget: int,
     metric: str = "l2sq",
+    trials: int = 1,
 ):
-    """Pick ``budget`` rows of ``x`` by weighted D^p sampling.
+    """Pick ``budget`` rows of ``x`` by weighted D^p sampling (greedy over
+    ``trials`` candidates per step when ``trials > 1``).
 
     Returns (center indices (budget,) int32, min-dist of every point to the
     chosen set).  Zero-weight rows are never chosen.
@@ -60,8 +72,15 @@ def kmeanspp_seed(
         score = jnp.where(score.sum() > 0, score, w)
         logits = jnp.log(jnp.maximum(score, 1e-30))
         logits = jnp.where(w > 0, logits, -jnp.inf)
-        idx = jax.random.categorical(sk, logits).astype(jnp.int32)
-        d = _dist_to(x, x[idx], metric)
+        if trials == 1:
+            idx = jax.random.categorical(sk, logits).astype(jnp.int32)
+            d = _dist_to(x, x[idx], metric)
+        else:
+            cand = jax.random.categorical(sk, logits, shape=(trials,))
+            dc = jax.vmap(lambda i: _dist_to(x, x[i], metric))(cand)
+            pot = jnp.sum(w * jnp.minimum(mind, dc), axis=1)   # (trials,)
+            best = jnp.argmin(pot)
+            idx, d = cand[best].astype(jnp.int32), dc[best]
         mind = jnp.minimum(mind, d)
         return (key, mind, chosen_any | True), idx
 

@@ -17,7 +17,8 @@ inline.  This module replaces that plumbing with:
   the single object threaded through the algorithm layers, or installed
   process-wide with ``set_default_policy``.  ``backend="auto"`` picks the
   best supported implementation for the current platform (Pallas on TPU,
-  blocked elsewhere) without the caller knowing;
+  blocked elsewhere) without the caller knowing.  An explicitly named
+  backend is served as named or raises — it never falls back;
 * an **autotuner** that benchmarks candidate ``block_n`` tile sizes per
   (op, backend, metric, shape-bucket, platform) and caches the winner in a
   JSON file under ``~/.cache/repro_kernels/`` (override the location with
@@ -67,18 +68,20 @@ class KernelPolicy:
     backend   — "auto" (pick per platform/capability), or an explicit
                 registry name.  An explicit backend that cannot serve a
                 particular call (e.g. the Pallas lloyd kernel under the l1
-                metric) falls back to auto selection for that call, exactly
-                like the inline ``if use_pallas and metric in (...)``
-                branches it replaces.
+                metric, or the Pallas kernels off a TPU) raises.
     block_n   — row-tile size; None means "backend default, or autotuned
                 when ``autotune`` is set".
     autotune  — measure candidate block_n values for this op/shape-bucket
                 (cached on disk) instead of using the backend default.
+    interpret — run the Pallas kernels in the Pallas interpreter (needs
+                ``backend="pallas"``): how tests check the kernels on a
+                host without a TPU.  Never chosen implicitly.
     """
 
     backend: str = "auto"
     block_n: Optional[int] = None
     autotune: bool = False
+    interpret: bool = False
 
     def __post_init__(self):
         if self.backend not in BACKENDS:
@@ -89,6 +92,10 @@ class KernelPolicy:
                                or isinstance(bn, bool) or bn < 1):
             raise ValueError(
                 f"block_n must be None or an int >= 1, got {bn!r}")
+        if self.interpret and self.backend != "pallas":
+            raise ValueError(
+                f"interpret=True runs the Pallas interpreter and needs "
+                f"backend='pallas', got backend={self.backend!r}")
 
 
 class Registration(NamedTuple):
@@ -224,7 +231,10 @@ def select_backend(
     dtype=np.float32,
     platform: Optional[str] = None,
 ) -> Registration:
-    """Pick the registration serving this call under ``policy``."""
+    """Pick the registration serving this call under ``policy``.
+
+    An explicit backend that cannot serve the call raises: a run that
+    named Pallas must not quietly measure or serve something else."""
     policy = policy if policy is not None else get_default_policy()
     platform = platform or jax.default_backend()
     regs = registered_backends(op)
@@ -234,10 +244,22 @@ def select_backend(
             raise ValueError(
                 f"op {op!r} has no backend {policy.backend!r}; "
                 f"registered: {sorted(regs)}")
-        if reg.supports(metric, platform, dtype, n, m, d):
-            return reg
-        # Explicit-but-unsupported falls back to auto selection for this
-        # call (the old inline `if use_pallas and metric in (...)` shape).
+        if not reg.supports(metric, platform, dtype, n, m, d):
+            raise ValueError(
+                f"backend {policy.backend!r} cannot serve op {op!r} with "
+                f"metric={metric!r} on platform {platform!r} for shape "
+                f"(n={n}, m={m}, d={d}); name a backend that can, or 'auto'")
+        if reg.name == "pallas":
+            if policy.interpret:
+                return reg._replace(
+                    impl=functools.partial(reg.impl, interpret=True))
+            if platform != "tpu":
+                raise ValueError(
+                    f"backend 'pallas' compiles for a TPU and this process "
+                    f"runs on {platform!r}; run on a TPU, or ask for the "
+                    f"interpreter with KernelPolicy(backend='pallas', "
+                    f"interpret=True)")
+        return reg
     candidates = [
         r for r in regs.values()
         if r.priority(platform) >= 0

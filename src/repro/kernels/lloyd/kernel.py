@@ -21,8 +21,8 @@ rows sit at 1e15 so they never win an argmin; padded x rows carry weight 0
 so they contribute nothing.
 
 Metrics: l2sq / l2 (assignment distance; the update is the weighted mean in
-both cases — k-means-- is a means algorithm).  l1 assignment falls back to
-the pdist kernel + jnp scatter in ops.py (no MXU win to be had).
+both cases — k-means-- is a means algorithm).  l1 has no kernel here (no
+MXU win to be had): auto selection serves it with the blocked backend.
 """
 from __future__ import annotations
 
@@ -32,8 +32,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.pdist.ref import F32
+
 _BIG = 3.0e38
 _PAD_COORD = 1.0e15
+# (BN, K) f32 elements per row tile.  The tile's distance and one-hot blocks
+# plus the f32-precision matmul temporaries fit a v5e kernel's VMEM at
+# 2^20 (BN=256 at K=4096) and overflow it at 2^21, so BN shrinks as K grows.
+_MAX_TILE = 1 << 20
 
 
 def _kernel(x_ref, w_ref, c_ref, sums_ref, counts_ref, assign_ref, dist_ref,
@@ -50,7 +56,7 @@ def _kernel(x_ref, w_ref, c_ref, sums_ref, counts_ref, assign_ref, dist_ref,
     c = c_ref[...].astype(jnp.float32)            # (K, d)
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)
     c2 = jnp.sum(c * c, axis=-1)
-    xc = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
+    xc = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())), precision=F32,
                              preferred_element_type=jnp.float32)
     dist = jnp.maximum(x2 + c2[None, :] - 2.0 * xc, 0.0)   # (BN, K)
     if sqrt:
@@ -65,6 +71,7 @@ def _kernel(x_ref, w_ref, c_ref, sums_ref, counts_ref, assign_ref, dist_ref,
     onehot = (iota == aloc[:, None]).astype(jnp.float32) * w   # (BN, K)
     # scatter-add as MXU matmul: (K, BN) @ (BN, d)
     sums_ref[...] += jax.lax.dot_general(onehot, x, (((0,), (0,)), ((), ())),
+                                         precision=F32,
                                          preferred_element_type=jnp.float32)
     counts_ref[...] += jnp.sum(onehot, axis=0, keepdims=True).T  # (K, 1)
 
@@ -81,16 +88,18 @@ def lloyd_step_pallas(
     *,
     metric: str = "l2sq",
     bn: int = 1024,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
+    """``interpret=True`` runs the Pallas interpreter (off-chip tests only);
+    otherwise the kernel compiles for the TPU or fails."""
     if metric not in ("l2sq", "l2"):
-        raise ValueError("lloyd kernel supports l2sq/l2; l1 uses the ops.py fallback")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        raise ValueError("lloyd kernel supports l2sq/l2; l1 is served by "
+                         "the blocked backend")
     n, d = x.shape
     k = c.shape[0]
-    bn = min(bn, _pad_to(n, 8))
-    np_, kp, dp = _pad_to(n, bn), _pad_to(k, 128), _pad_to(d, 128)
+    kp = _pad_to(k, 128)
+    bn = max(8, min(bn, _pad_to(n, 8), _MAX_TILE // kp // 8 * 8))
+    np_, dp = _pad_to(n, bn), _pad_to(d, 128)
     xp = jnp.pad(x, ((0, np_ - n), (0, dp - d)))
     wp = jnp.pad(w.reshape(-1, 1), ((0, np_ - n), (0, 0)))
     cp = jnp.pad(c, ((0, kp - k), (0, dp - d)), constant_values=_PAD_COORD)

@@ -9,8 +9,7 @@ Backends (registered with ``repro.kernels.dispatch``):
   * ``ref``     — the pure-jnp oracle in ``ref.py``.
 
 ``backend="auto"`` picks Pallas on TPU for l2sq/l2 and blocked elsewhere;
-an explicit ``pallas`` policy under the l1 metric falls back the same way
-the old inline ``if use_pallas and metric in ("l2sq", "l2")`` branch did.
+an explicit ``pallas`` policy under the l1 metric raises (no kernel).
 """
 from __future__ import annotations
 
@@ -25,6 +24,7 @@ from repro.kernels import dispatch
 from repro.kernels.dispatch import KernelPolicy
 from repro.kernels.lloyd.ref import lloyd_step_ref
 from repro.kernels.pdist.ops import min_argmin_blocked
+from repro.kernels.pdist.ref import F32
 
 _DEFAULT_BLOCK_N = 16384
 
@@ -46,6 +46,7 @@ def accumulate_by_assignment(x, w, amin, k: int):
     onehot = (amin[:, None] == jnp.arange(k, dtype=amin.dtype)[None, :])
     onehot = onehot.astype(jnp.float32) * w[:, None]           # (n, k)
     sums = jax.lax.dot_general(onehot, x, (((0,), (0,)), ((), ())),
+                               precision=F32,
                                preferred_element_type=jnp.float32)
     return sums, onehot.sum(axis=0)
 
@@ -90,9 +91,10 @@ def lloyd_step_reference(x, w, c, *, metric: str = "l2sq", block_n: int = 0):
     make_args=_lloyd_args,
 )
 def lloyd_step_pallas_backend(x, w, c, *, metric: str = "l2sq",
-                              block_n: int = 1024):
+                              block_n: int = 1024, interpret: bool = False):
     from repro.kernels.lloyd.kernel import lloyd_step_pallas
-    return lloyd_step_pallas(x, w, c, metric=metric, bn=block_n)
+    return lloyd_step_pallas(x, w, c, metric=metric, bn=block_n,
+                             interpret=interpret)
 
 
 def lloyd_step(

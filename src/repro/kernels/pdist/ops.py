@@ -4,7 +4,9 @@
 
 Dispatches through the backend registry (``repro.kernels.dispatch``):
 
-  * ``pallas``  — the TPU kernel (``kernel.py``); interpret mode off-TPU,
+  * ``pallas``  — the TPU kernel (``kernel.py``); off a TPU only in the
+    Pallas interpreter, and only when the policy names it
+    (``KernelPolicy(backend="pallas", interpret=True)``),
   * ``blocked`` — a chunked pure-jnp path that never materializes more than
     ``block_n × m`` distances at once (the (n, m) matrix for the paper's
     datasets would be ~GBs; chunking keeps the working set cache-sized on
@@ -109,9 +111,11 @@ def min_argmin_reference(x: jnp.ndarray, c: jnp.ndarray, *,
     tune_candidates=(256, 512, 1024, 2048),
 )
 def min_argmin_pallas_backend(x: jnp.ndarray, c: jnp.ndarray, *,
-                              metric: str = "l2sq", block_n: int = 512):
+                              metric: str = "l2sq", block_n: int = 512,
+                              interpret: bool = False):
     from . import kernel as _kernel  # deferred: pallas import is optional
-    return _kernel.min_argmin_pallas(x, c, metric=metric, bn=block_n)
+    return _kernel.min_argmin_pallas(x, c, metric=metric, bn=block_n,
+                                     interpret=interpret)
 
 
 def min_argmin(

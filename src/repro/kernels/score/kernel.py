@@ -18,14 +18,15 @@ path:
   dmin / max(threshold, 1e-30) — the distance never returns to HBM just
   to be divided.
 
-The threshold is a (1, 1) block broadcast to every grid step.  Metrics,
-padding sentinels, and tie-breaking (strict ``<`` keeps the earliest
-center tile; ``jnp.argmin`` keeps the first minimum within a tile) match
-the pdist kernel exactly, so the fused outputs agree with the composed
-``min_argmin`` + divide path within float tolerance with bit-equal
-argmins (asserted in tests/test_dispatch.py).  Cosine is excluded for the
-same reason as pdist: a far-away padding sentinel is a direction, not a
-distance, under a normalized metric.
+The threshold is a (1, 1) block broadcast to every grid step.  The tile
+distances, padding sentinels and running-min fold are the pdist kernel's
+own helpers (``repro.kernels.pdist.kernel``), so metrics and tie-breaking
+(strict ``<`` keeps the earliest center tile; ``jnp.argmin`` keeps the
+first minimum within a tile) match it exactly, and the fused outputs
+agree with the composed ``min_argmin`` + divide path within float
+tolerance with bit-equal argmins (asserted in tests/test_dispatch.py).
+Cosine is excluded for the same reason as pdist: a far-away padding
+sentinel is a direction, not a distance, under a normalized metric.
 """
 from __future__ import annotations
 
@@ -36,82 +37,40 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_BIG = 3.0e38  # python float: jnp scalars would be captured as kernel consts
-_PAD_COORD = 1.0e15  # padded center rows sit absurdly far away
+from repro.kernels.pdist.kernel import (fold_min, init_min, l1_tile_dist,
+                                        l2_tile_dist, pad_operands,
+                                        tile_specs)
+
 _EPS = 1e-30  # threshold guard, matches the composed serving path
 
 
-def _l2_score_kernel(x_ref, c_ref, thr_ref, dmin_ref, amin_ref, score_ref,
-                     *, bm: int, nm: int, sqrt: bool):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        dmin_ref[...] = jnp.full_like(dmin_ref, _BIG)
-        amin_ref[...] = jnp.zeros_like(amin_ref)
-
-    x = x_ref[...].astype(jnp.float32)           # (BN, d)
-    c = c_ref[...].astype(jnp.float32)           # (BM, d)
-    x2 = jnp.sum(x * x, axis=-1, keepdims=True)  # (BN, 1)
-    c2 = jnp.sum(c * c, axis=-1)                 # (BM,)
-    # MXU: (BN, d) @ (d, BM)
-    xc = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    dist = jnp.maximum(x2 + c2[None, :] - 2.0 * xc, 0.0)  # (BN, BM)
-    if sqrt:
-        dist = jnp.sqrt(dist)
-    dloc = jnp.min(dist, axis=1, keepdims=True)            # (BN, 1)
-    aloc = jnp.argmin(dist, axis=1).astype(jnp.int32)[:, None] + j * bm
-
-    better = dloc < dmin_ref[...]
-    dmin_ref[...] = jnp.where(better, dloc, dmin_ref[...])
-    amin_ref[...] = jnp.where(better, aloc, amin_ref[...])
-
+def _score_epilogue(j, nm, thr_ref, dmin_ref, score_ref):
     @pl.when(j == nm - 1)
     def _score():
         thr = jnp.maximum(thr_ref[0, 0], _EPS)
         score_ref[...] = dmin_ref[...] / thr
 
 
-def _l1_score_kernel(x_ref, c_ref, thr_ref, dmin_ref, amin_ref, score_ref,
-                     acc_ref, *, bm: int, nm: int, nd: int):
+def _l2_score_kernel(x_ref, c_ref, thr_ref, dmin_ref, amin_ref, score_ref,
+                     *, bm: int, nm: int, sqrt: bool):
     j = pl.program_id(1)
-    kd = pl.program_id(2)
-
-    @pl.when((j == 0) & (kd == 0))
-    def _init():
-        dmin_ref[...] = jnp.full_like(dmin_ref, _BIG)
-        amin_ref[...] = jnp.zeros_like(amin_ref)
-
-    @pl.when(kd == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...].astype(jnp.float32)           # (BN, BD)
-    c = c_ref[...].astype(jnp.float32)           # (BM, BD)
-    acc_ref[...] += jnp.abs(x[:, None, :] - c[None, :, :]).sum(-1)
-
-    @pl.when(kd == nd - 1)
-    def _reduce():
-        dist = acc_ref[...]
-        dloc = jnp.min(dist, axis=1, keepdims=True)
-        aloc = jnp.argmin(dist, axis=1).astype(jnp.int32)[:, None] + j * bm
-        better = dloc < dmin_ref[...]
-        dmin_ref[...] = jnp.where(better, dloc, dmin_ref[...])
-        amin_ref[...] = jnp.where(better, aloc, amin_ref[...])
-
-    @pl.when((j == nm - 1) & (kd == nd - 1))
-    def _score():
-        thr = jnp.maximum(thr_ref[0, 0], _EPS)
-        score_ref[...] = dmin_ref[...] / thr
+    init_min(j, dmin_ref, amin_ref)
+    fold_min(l2_tile_dist(x_ref, c_ref, sqrt=sqrt), j, bm=bm,
+             dmin_ref=dmin_ref, amin_ref=amin_ref)
+    _score_epilogue(j, nm, thr_ref, dmin_ref, score_ref)
 
 
-def _pad_to(v: int, mult: int) -> int:
-    return ((v + mult - 1) // mult) * mult
+def _l1_score_kernel(x_ref, ct_ref, thr_ref, dmin_ref, amin_ref, score_ref,
+                     acc_ref, *, bm: int, nm: int, d: int):
+    j = pl.program_id(1)
+    init_min(j, dmin_ref, amin_ref)
+    fold_min(l1_tile_dist(x_ref, ct_ref, acc_ref, d=d), j, bm=bm,
+             dmin_ref=dmin_ref, amin_ref=amin_ref)
+    _score_epilogue(j, nm, thr_ref, dmin_ref, score_ref)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("metric", "bn", "bm", "bd", "interpret"))
+                   static_argnames=("metric", "bn", "bm", "interpret"))
 def score_pallas(
     x: jnp.ndarray,
     c: jnp.ndarray,
@@ -120,75 +79,36 @@ def score_pallas(
     metric: str = "l2sq",
     bn: int = 512,
     bm: int = 128,
-    bd: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
-    """Fused (min distance, argmin, dist/threshold) — Pallas path."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    """Fused (min distance, argmin, dist/threshold) — Pallas path.
+    ``interpret=True`` runs the Pallas interpreter (off-chip tests only)."""
     n, d = x.shape
-    m = c.shape[0]
-    bn = min(bn, _pad_to(n, 8))
-    bm = min(bm, _pad_to(m, 128))
-    np_, mp = _pad_to(n, bn), _pad_to(m, bm)
-    xp = jnp.pad(x, ((0, np_ - n), (0, 0)))
-    cp = jnp.pad(c, ((0, mp - m), (0, 0)), constant_values=_PAD_COORD)
+    xp, cp, bn, bm = pad_operands(x, c, metric, bn, bm)
+    np_, dp = xp.shape
+    mp = cp.shape[1] if metric == "l1" else cp.shape[0]
+    nm = mp // bm
     thr = jnp.reshape(threshold, (1, 1)).astype(jnp.float32)
-    out_shape = [
-        jax.ShapeDtypeStruct((np_, 1), jnp.float32),
-        jax.ShapeDtypeStruct((np_, 1), jnp.int32),
-        jax.ShapeDtypeStruct((np_, 1), jnp.float32),
-    ]
-
-    if metric in ("l2sq", "l2"):
-        dp = _pad_to(d, 128)
-        xp = jnp.pad(xp, ((0, 0), (0, dp - d)))
-        cp = jnp.pad(cp, ((0, 0), (0, dp - d)))  # both pad w/ same const -> dist 0
-        nm = mp // bm
-        grid = (np_ // bn, nm)
-        dmin, amin, score = pl.pallas_call(
-            functools.partial(_l2_score_kernel, bm=bm, nm=nm,
-                              sqrt=(metric == "l2")),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((bn, dp), lambda i, j: (i, 0)),
-                pl.BlockSpec((bm, dp), lambda i, j: (j, 0)),
-                pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-                pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-                pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-            ],
-            out_shape=out_shape,
-            interpret=interpret,
-        )(xp, cp, thr)
-    elif metric == "l1":
-        dp = _pad_to(d, 128)
-        bd = min(bd, dp)
-        dp = _pad_to(dp, bd)
-        xp = jnp.pad(xp, ((0, 0), (0, dp - d)))
-        cp = jnp.pad(cp, ((0, 0), (0, dp - d)))
-        nd = dp // bd
-        nm = mp // bm
-        grid = (np_ // bn, nm, nd)
-        dmin, amin, score = pl.pallas_call(
-            functools.partial(_l1_score_kernel, bm=bm, nm=nm, nd=nd),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((bn, bd), lambda i, j, kd: (i, kd)),
-                pl.BlockSpec((bm, bd), lambda i, j, kd: (j, kd)),
-                pl.BlockSpec((1, 1), lambda i, j, kd: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((bn, 1), lambda i, j, kd: (i, 0)),
-                pl.BlockSpec((bn, 1), lambda i, j, kd: (i, 0)),
-                pl.BlockSpec((bn, 1), lambda i, j, kd: (i, 0)),
-            ],
-            out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((bn, bm), jnp.float32)],
-            interpret=interpret,
-        )(xp, cp, thr)
+    if metric == "l1":
+        kernel = functools.partial(_l1_score_kernel, bm=bm, nm=nm, d=d)
+        scratch = [pltpu.VMEM((bn, bm), jnp.float32)]
     else:
-        raise ValueError(f"unknown metric {metric!r}")
+        kernel = functools.partial(_l2_score_kernel, bm=bm, nm=nm,
+                                   sqrt=(metric == "l2"))
+        scratch = []
+    row = pl.BlockSpec((bn, 1), lambda i, j: (i, 0))
+    dmin, amin, score = pl.pallas_call(
+        kernel,
+        grid=(np_ // bn, nm),
+        in_specs=[*tile_specs(metric, bn, bm, dp),
+                  pl.BlockSpec((1, 1), lambda i, j: (0, 0))],
+        out_specs=[row, row, row],
+        out_shape=[
+            jax.ShapeDtypeStruct((np_, 1), jnp.float32),
+            jax.ShapeDtypeStruct((np_, 1), jnp.int32),
+            jax.ShapeDtypeStruct((np_, 1), jnp.float32),
+        ],
+        scratch_shapes=scratch,
+        interpret=interpret,
+    )(xp, cp, thr)
     return dmin[:n, 0], amin[:n, 0], score[:n, 0]

@@ -20,7 +20,8 @@ Backends (registry: ``repro.kernels.dispatch``):
     composed path.
   * ``pallas``  — one TPU kernel (``kernel.py``): double-buffered
     HBM→VMEM DMA over row tiles, centers VMEM-resident, score epilogue
-    in-register.  Interpret mode off-TPU (test-only, never auto-picked).
+    in-register.  Off a TPU only in the Pallas interpreter, when the
+    policy names it (test-only, never auto-picked).
   * ``int8``    — quantized-center variant: per-center symmetric scale
     (``max|c_i| / 127``), centers stored int8, rescaled to fp32 at the
     accumulate, then the blocked single pass.  It CHANGES results
@@ -164,10 +165,10 @@ def score_blocked(x: jnp.ndarray, c: jnp.ndarray, threshold, *,
 )
 def score_pallas_backend(x: jnp.ndarray, c: jnp.ndarray, threshold, *,
                          metric: str = "l2sq", block_n: int = 512,
-                         block_m: int = 128):
+                         block_m: int = 128, interpret: bool = False):
     from . import kernel as _kernel  # deferred: pallas import is optional
     return _kernel.score_pallas(x, c, threshold, metric=metric,
-                                bn=block_n, bm=block_m)
+                                bn=block_n, bm=block_m, interpret=interpret)
 
 
 @dispatch.register(

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core.collective import sites_mesh
 from repro.core.distributed import distributed_cluster
 from repro.kernels.dispatch import KernelPolicy
 from repro.launch.dryrun import HBM_BW, LINK_BW, PEAK_FLOPS, _jsonable
@@ -40,7 +41,7 @@ def main():
     args = ap.parse_args()
 
     s = 512 if args.multi else 256
-    mesh = jax.make_mesh((s,), ("sites",), devices=jax.devices()[:s])
+    mesh = sites_mesh(s)
     n, d = args.n_per_site, args.d
     x_s = jax.ShapeDtypeStruct((s, n, d), jnp.float32)
     key_s = jax.ShapeDtypeStruct((), jax.random.key(0).dtype)

@@ -27,10 +27,11 @@ Execution paths, same math:
   trees, the gather is a concatenation in site order — bit-identical to
   what the collective delivers — and communication is *accounted* (records
   and bytes) rather than performed;
-* ``use_shard_map=True`` with >= s devices: the gather + second level run
-  as one ``shard_map`` program over the ``sites`` mesh axis
+* ``use_shard_map=True``: the gather + second level run as one
+  ``shard_map`` program over the ``sites`` mesh axis
   (``repro.core.collective``), so on hardware the root exchange lowers to
-  one ICI collective per leaf of the payload.
+  one ICI collective per leaf of the payload.  It needs >= s devices and
+  raises at construction otherwise — it never quietly runs host-sim.
 
 The read path (micro-batched scoring, latency accounting) and the
 double-buffered async refresh are inherited from
@@ -72,7 +73,7 @@ class ShardedServiceConfig(BaseServiceConfig):
     n_sites: int = 4
     site_budget: str = "full"        # "full": t per site (window/adversarial
     #                                  safe); "paper": 2t/s (cheaper roots)
-    use_shard_map: bool = False      # real collective when devices allow
+    use_shard_map: bool = False      # real collective (needs >= n_sites devices)
 
     def site_t(self) -> int:
         if self.site_budget == "full":
@@ -122,6 +123,11 @@ class ShardedStreamService(ServingFrontEnd):
                  key: jax.Array | None = None):
         if cfg.n_sites < 1:
             raise ValueError(f"n_sites must be >= 1, got {cfg.n_sites}")
+        if cfg.use_shard_map and len(jax.devices()) < cfg.n_sites:
+            raise RuntimeError(
+                f"use_shard_map needs >= {cfg.n_sites} devices for "
+                f"{cfg.n_sites} sites, have {len(jax.devices())}; drop "
+                f"use_shard_map to run host-simulated")
         super().__init__(cfg)
         key = key if key is not None else jax.random.key(cfg.seed)
         kt, self._model_key = jax.random.split(key)
@@ -223,7 +229,7 @@ class ShardedStreamService(ServingFrontEnd):
         wts = np.stack([r[1] for r in roots])          # (s, rows)
         val = np.stack([r[2] for r in roots])          # (s, rows)
         one_site = (roots[0][0], roots[0][1], roots[0][2])
-        use_sm = cfg.use_shard_map and len(jax.devices()) >= cfg.n_sites
+        use_sm = cfg.use_shard_map
         site_bytes = payload_bytes(one_site)
         self.last_refresh = RefreshStats(
             version=version,

@@ -35,7 +35,7 @@ except ImportError:
     HAVE_HYPOTHESIS = False
 
 from repro.api import PipelineConfig, Session, pipeline_config
-from repro.core import distributed_cluster, simulate_coordinator
+from repro.core import distributed_cluster, simulate_coordinator, sites_mesh
 from repro.data.synthetic import gauss
 from repro.kernels.dispatch import KernelPolicy
 from repro.stream import (BaseServiceConfig, ServiceConfig,
@@ -260,7 +260,7 @@ def test_oneshot_session_matches_distributed_cluster_shard_map(data):
     sess.fit(x)
     res = distributed_cluster(
         jnp.asarray(x)[None], jax.random.key(5),
-        jax.make_mesh((1,), ("sites",)), k=4, t=12,
+        sites_mesh(1), k=4, t=12,
         summarizer=cfg.summarizer, policy=cfg.kernels)
     assert (sess.result["centers"] == np.asarray(res.centers)).all()
     out = np.asarray(res.outlier_ids)
@@ -282,8 +282,7 @@ _ONESHOT_SHARD_MAP_EQ = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.api import Session, pipeline_config
-    from repro.core import distributed_cluster
-    from repro.core.collective import sites_mesh
+    from repro.core import distributed_cluster, sites_mesh
     from repro.data.synthetic import gauss
 
     x, _ = gauss(n_centers=4, per_center=500, d=3, t=16, sigma=0.05,

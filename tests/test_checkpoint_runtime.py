@@ -238,7 +238,6 @@ _ROBUST = textwrap.dedent("""
     import json
     import numpy as np, jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.core.collective import shard_map
     from repro.runtime.robust_agg import robust_mean_grads
 
     mesh = jax.make_mesh((8,), ("data",))
@@ -250,8 +249,8 @@ _ROBUST = textwrap.dedent("""
         return mean["w"][None], jnp.stack([n_honest.astype(jnp.float32),
                                            flagged.astype(jnp.float32)])[None]
 
-    fn = shard_map(per_replica, mesh, in_specs=P("data"),
-                   out_specs=(P("data"), P("data")))
+    fn = jax.shard_map(per_replica, mesh=mesh, in_specs=P("data"),
+                       out_specs=(P("data"), P("data")))
     rng = np.random.default_rng(0)
     base = rng.normal(size=D).astype(np.float32)
     grads = np.stack([base + rng.normal(scale=0.01, size=D).astype(np.float32)

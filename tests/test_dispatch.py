@@ -8,11 +8,12 @@ Parity contract (the acceptance bar for any new backend):
     path reassociates the matmul (XLA tiles a (64, d) block differently
     from the full array), so there the contract is distances within one
     float ulp-scale tolerance and bit-equal argmins;
-  * ``pallas`` (interpret mode on CPU) matches ``ref`` within float
-    tolerance, with identical argmins away from ties.
+  * ``pallas`` (in the Pallas interpreter on CPU, asked for by name with
+    ``interpret=True``) matches ``ref`` within float tolerance, with
+    identical argmins away from ties.
 
-Plus: auto selection picks blocked off-TPU, explicit-but-unsupported
-backends fall back the way the old inline dispatch did, the process-wide
+Plus: auto selection picks blocked off-TPU, an explicitly named backend
+that cannot serve a call raises instead of falling back, the process-wide
 default policy threads into jitted callers, the autotuner caches its
 measured ``block_n`` under ``$REPRO_KERNELS_CACHE``, and the removed
 ``use_pallas=``/``block_n=`` aliases raise a ``TypeError`` pointing at
@@ -37,6 +38,8 @@ METRICS = ["l2sq", "l2", "l1"]
 # ragged on purpose: nothing divides the tile sizes; the 200-center cases
 # exercise the l1 center-chunking scan (mc=64) in the blocked path
 RAGGED_SHAPES = [(37, 3, 5), (257, 65, 11), (1001, 200, 18), (130, 129, 3)]
+# off a TPU the Pallas kernels run only in the interpreter, asked for by name
+_INTERPRET = KernelPolicy(backend="pallas", interpret=True)
 
 
 def _data(n, m, d):
@@ -91,8 +94,7 @@ def test_min_argmin_blocked_chunked_equals_ref(shape, metric):
 def test_min_argmin_pallas_interpret_close_to_ref(shape, metric):
     n, m, d = shape
     x, c, _ = _data(n, m, d)
-    dp, ap_ = min_argmin(x, c, metric=metric,
-                         policy=KernelPolicy(backend="pallas"))
+    dp, ap_ = min_argmin(x, c, metric=metric, policy=_INTERPRET)
     dr, ar = min_argmin_ref(x, c, metric)
     np.testing.assert_allclose(np.asarray(dp), np.asarray(dr),
                                rtol=1e-5, atol=1e-5)
@@ -125,8 +127,7 @@ def test_lloyd_blocked_equals_ref(shape, metric):
 @pytest.mark.parametrize("metric", ["l2sq", "l2"])
 def test_lloyd_pallas_interpret_close_to_ref(metric):
     x, c, w = _data(513, 37, 9)
-    sp, cp, ap_, dp = lloyd_step(x, w, c, metric=metric,
-                                 policy=KernelPolicy(backend="pallas"))
+    sp, cp, ap_, dp = lloyd_step(x, w, c, metric=metric, policy=_INTERPRET)
     sr, cr, ar, dr = lloyd_step_ref(x, w, c, metric)
     assert (np.asarray(ap_) == np.asarray(ar)).all()
     np.testing.assert_allclose(np.asarray(sp), np.asarray(sr),
@@ -159,17 +160,30 @@ def test_auto_on_tpu_prefers_pallas():
 
 
 def test_explicit_unsupported_backend_falls_back():
-    # the old `if use_pallas and metric in ("l2sq", "l2")` semantics: an l1
-    # lloyd call under an explicit pallas policy silently uses the best
-    # supported backend instead of erroring
-    reg = dispatch.select_backend("lloyd_step", KernelPolicy(backend="pallas"),
-                                  metric="l1", n=100, m=10, d=4)
-    assert reg.name == "blocked"
-    x, c, w = _data(64, 70, 5)   # m > 64: center-chunking path
-    s1, c1, a1, d1 = lloyd_step(x, w, c, metric="l1",
-                                policy=KernelPolicy(backend="pallas"))
-    sr, cr, ar, dr = lloyd_step_ref(x, w, c, "l1")
-    assert (np.asarray(a1) == np.asarray(ar)).all()
+    """An explicitly named backend that cannot serve a call no longer
+    falls back to auto selection: it raises, naming the backend, the op
+    and the platform.  So does Pallas off a TPU unless the interpreter is
+    asked for by name."""
+    with pytest.raises(ValueError, match="'pallas' cannot serve op "
+                                         "'lloyd_step' with metric='l1'"):
+        dispatch.select_backend("lloyd_step", KernelPolicy(backend="pallas"),
+                                metric="l1", n=100, m=10, d=4,
+                                platform="tpu")
+    x, c, w = _data(64, 70, 5)
+    with pytest.raises(ValueError, match="cannot serve"):
+        lloyd_step(x, w, c, metric="l1", policy=_INTERPRET)
+    for op in ("min_argmin", "lloyd_step", "score"):
+        with pytest.raises(ValueError, match="compiles for a TPU"):
+            dispatch.select_backend(op, KernelPolicy(backend="pallas"),
+                                    metric="l2sq", n=100, m=10, d=4,
+                                    platform="cpu")
+        reg = dispatch.select_backend(op, _INTERPRET, metric="l2sq",
+                                      n=100, m=10, d=4, platform="cpu")
+        assert reg.name == "pallas"
+    with pytest.raises(ValueError, match="compiles for a TPU"):
+        min_argmin(x, c, policy=KernelPolicy(backend="pallas"))
+    with pytest.raises(ValueError, match="needs backend='pallas'"):
+        KernelPolicy(interpret=True)
 
 
 def test_unknown_backend_raises():
@@ -319,7 +333,7 @@ def test_score_pallas_interpret_parity_vs_ref(shape, metric):
     x, c, _ = _data(n, m, d)
     dr, ar, sr = _score_ref(x, c, metric)
     dp, ap_, sp = score(x, c, jnp.float32(_THR), metric=metric,
-                        policy=KernelPolicy(backend="pallas"))
+                        policy=_INTERPRET)
     assert (np.asarray(ap_) == np.asarray(ar)).all()
     np.testing.assert_allclose(np.asarray(dp), np.asarray(dr),
                                rtol=1e-5, atol=1e-5)
@@ -345,10 +359,13 @@ def test_score_predicates_cosine_excluded_from_pallas_only():
     for name in ("ref", "blocked", "int8"):
         assert regs[name].supports("cosine", "cpu", np.float32, 100, 10, 4)
     assert not regs["pallas"].supports("cosine", "tpu", np.float32, 100, 10, 4)
-    # explicit-but-unsupported falls back to auto, like pdist does
-    reg = dispatch.select_backend("score", KernelPolicy(backend="pallas"),
-                                  metric="cosine", n=100, m=10, d=4,
-                                  platform="tpu")
+    # explicit-but-unsupported raises, like pdist does; auto routes around
+    with pytest.raises(ValueError, match="cannot serve"):
+        dispatch.select_backend("score", KernelPolicy(backend="pallas"),
+                                metric="cosine", n=100, m=10, d=4,
+                                platform="tpu")
+    reg = dispatch.select_backend("score", KernelPolicy(), metric="cosine",
+                                  n=100, m=10, d=4, platform="tpu")
     assert reg.name == "blocked"
 
 

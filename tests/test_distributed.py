@@ -11,7 +11,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import (distributed_cluster, local_budget, simulate_coordinator)
+from repro.core import (distributed_cluster, local_budget, simulate_coordinator,
+                        sites_mesh)
 from repro.core.metrics import outlier_scores
 from repro.data.synthetic import gauss, partition
 
@@ -47,7 +48,7 @@ def test_adversarial_partition_larger_budget_still_works():
 
 def test_shardmap_single_device_matches_simulate_quality():
     x, out_ids = gauss(n_centers=10, per_center=400, t=80, sigma=0.1, seed=6)
-    mesh = jax.make_mesh((1,), ("sites",))
+    mesh = sites_mesh(1)
     res = distributed_cluster(jnp.asarray(x)[None], jax.random.key(0), mesh,
                               k=10, t=80)
     oi = np.asarray(res.outlier_ids)
@@ -63,14 +64,14 @@ _SUBPROCESS = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import numpy as np, jax, jax.numpy as jnp
-    from repro.core import distributed_cluster
+    from repro.core import distributed_cluster, sites_mesh
     from repro.core.metrics import outlier_scores
     from repro.data.synthetic import gauss, partition
 
     x, out_ids = gauss(n_centers=10, per_center=400, t=160, sigma=0.1, seed=1)
     parts, gids = partition(x, 8, "random", seed=3, outlier_ids=out_ids)
     xs = jnp.asarray(np.stack(parts))
-    mesh = jax.make_mesh((8,), ("sites",))
+    mesh = sites_mesh(8)
     res = distributed_cluster(xs, jax.random.key(0), mesh, k=10, t=160)
     conc = np.concatenate(gids)
     oi = np.asarray(res.outlier_ids); oi = conc[oi[oi >= 0]]

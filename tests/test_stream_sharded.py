@@ -54,16 +54,21 @@ def _covers_true_centers(model, true_c, tol=0.5):
 
 
 # ------------------------------------------------- sharded == single host
-def test_sharded_matches_single_host_interleaved():
+@pytest.mark.parametrize("seed", [3, 7, 11])
+def test_sharded_matches_single_host_interleaved(seed):
     """Acceptance: s=4 sites on an interleaved stream reproduce the
-    single-host refresh model up to permutation, via roots only."""
+    single-host refresh model up to permutation, via roots only.
+
+    Seeds 3, 7 and 11 are ones where plain k-means++ seeding parked two
+    centers on far outliers (3: both services, 7: single host, 11:
+    sharded); greedy seeding must cover the true centers on every one."""
     x, planted, true_c = _lattice_stream(seed=0)
     k, t, d = true_c.shape[0], planted.size, x.shape[1]
     single = StreamService(ServiceConfig(
-        dim=d, k=k, t=t, leaf_size=512, refresh_every=2048, seed=3))
+        dim=d, k=k, t=t, leaf_size=512, refresh_every=2048, seed=seed))
     shard = ShardedStreamService(ShardedServiceConfig(
         dim=d, k=k, t=t, n_sites=4, leaf_size=512, refresh_every=2048,
-        seed=3))
+        seed=seed))
     single.ingest(x)
     shard.ingest(x)
     m1, m2 = single.refresh(), shard.refresh()
@@ -165,6 +170,7 @@ _SHARD_MAP_EQ = textwrap.dedent("""
                                              np.asarray(mc.centers))),
         "threshold_equal": float(mh.threshold) == float(mc.threshold),
         "cost_equal": float(mh.cost) == float(mc.cost),
+        "one_device": all(len(a.sharding.device_set) == 1 for a in mc),
         "comm_bytes": [host.last_refresh.comm_bytes,
                        coll.last_refresh.comm_bytes]}))
 """)
@@ -182,7 +188,20 @@ def test_shard_map_refresh_bit_identical_to_host_sim_subprocess():
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["paths"] == ["host-sim", "shard_map"]
     assert res["centers_equal"] and res["threshold_equal"] and res["cost_equal"]
+    # the gathered model lands on one device, ready for the score kernel
+    assert res["one_device"]
     assert res["comm_bytes"][0] == res["comm_bytes"][1] > 0
+
+
+def test_shard_map_with_too_few_devices_raises():
+    """use_shard_map never quietly degrades to host-sim: with fewer
+    devices than sites the service refuses to start, as the oneshot
+    Session does."""
+    import jax
+    n = len(jax.devices()) + 1
+    with pytest.raises(RuntimeError, match=f"needs >= {n} devices"):
+        ShardedStreamService(ShardedServiceConfig(
+            dim=3, k=2, t=4, n_sites=n, use_shard_map=True))
 
 
 # ------------------------------------------------- checkpointing

@@ -12,7 +12,7 @@ try:  # optional: only the property tests need hypothesis
 except ImportError:
     HAVE_HYPOTHESIS = False
 
-from repro.core import distributed_cluster, simulate_coordinator
+from repro.core import distributed_cluster, simulate_coordinator, sites_mesh
 from repro.data.synthetic import gauss, partition, susy_like
 from repro.kernels.pdist.ops import min_argmin
 from repro.stream import (ServiceConfig, ShardedServiceConfig,
@@ -215,7 +215,7 @@ def test_tree_default_matches_explicit_paper_policy_bitwise():
 
 def test_distributed_cluster_default_matches_paper_policy_bitwise():
     x, _ = gauss(n_centers=6, per_center=200, t=40, sigma=0.1, seed=9)
-    mesh = jax.make_mesh((1,), ("sites",))
+    mesh = sites_mesh(1)
     res_default = distributed_cluster(jnp.asarray(x)[None],
                                       jax.random.key(0), mesh, k=6, t=40)
     res_policy = distributed_cluster(
@@ -230,7 +230,7 @@ def test_distributed_cluster_default_matches_paper_policy_bitwise():
 # ------------------------------------------------------- per-site threading
 def test_distributed_cluster_uniform_site_path():
     x, _ = gauss(n_centers=6, per_center=200, t=40, sigma=0.1, seed=10)
-    mesh = jax.make_mesh((1,), ("sites",))
+    mesh = sites_mesh(1)
     res = distributed_cluster(
         jnp.asarray(x)[None], jax.random.key(0), mesh, k=6, t=40,
         summarizer=summarizer_policy("uniform", budget=300))
@@ -240,7 +240,7 @@ def test_distributed_cluster_uniform_site_path():
 
 def test_distributed_cluster_host_only_summarizer_raises():
     x = _data(n=400, seed=11)
-    mesh = jax.make_mesh((1,), ("sites",))
+    mesh = sites_mesh(1)
     with pytest.raises(ValueError, match="no fixed-shape site path"):
         distributed_cluster(jnp.asarray(x)[None], jax.random.key(0), mesh,
                             k=K, t=T, summarizer=SummarizerPolicy("ball_cover"))
@@ -364,10 +364,15 @@ def test_cosine_never_auto_selects_pallas():
     from repro.kernels import dispatch
     from repro.kernels.dispatch import KernelPolicy
 
-    for policy in (KernelPolicy(), KernelPolicy(backend="pallas")):
-        reg = dispatch.select_backend("min_argmin", policy, metric="cosine",
-                                      n=1000, m=32, d=8, platform="tpu")
-        assert reg.name != "pallas"
+    reg = dispatch.select_backend("min_argmin", KernelPolicy(),
+                                  metric="cosine", n=1000, m=32, d=8,
+                                  platform="tpu")
+    assert reg.name != "pallas"
+    # naming pallas for cosine is an error, not a silent substitution
+    with pytest.raises(ValueError, match="cannot serve"):
+        dispatch.select_backend("min_argmin", KernelPolicy(backend="pallas"),
+                                metric="cosine", n=1000, m=32, d=8,
+                                platform="tpu")
 
 
 def test_coreset_cosine_on_unit_normalized_susy():
