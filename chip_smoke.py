@@ -29,8 +29,8 @@ Four chips.  The sharded service with one site per chip
 refreshed model and scores bit-identical.  A oneshot ``Session`` with
 ``use_shard_map`` against ``distributed_cluster`` called directly on the
 same mesh (bitwise) and against host-simulated ``simulate_coordinator``
-(same algorithm, other summary code: scored recall and precision on the
-queries side by side).
+(same algorithm, other summary code): each path's summary keeps the
+planted mass, and the records its k-means-- flags carry it.
 
 Earlier lines report ``device_kind``, per-phase wall times with the
 compile time inside each phase, the tree's levels and records, the
@@ -74,6 +74,14 @@ CPU_PRECISION = 1.0
 # precision misses this by two orders of magnitude.
 DIST_TOL_ULPS = 64
 _EPS32 = float(np.finfo(np.float32).eps)
+
+# Four chips, oneshot sessions.  Summaries conserve mass, so the records
+# with planted ids should carry all t planted points; on a TPU v5 lite at
+# the KDD shape both paths read 4580.0 of 4580, as at n=60,000 on four
+# virtual CPU devices.  Outlier-mass recall (planted mass in the records
+# k-means-- flags, over t) read 1.0 on both paths at n=60,000 on the CPU.
+PLANTED_MASS_TOL = 0.01
+OUTLIER_MASS_RECALL_MIN = 0.99
 
 
 @dataclasses.dataclass(frozen=True)
@@ -417,13 +425,15 @@ def run_four_chips(size: Size = KDD_FOUR_CHIPS, n_sites: int = 4) -> Report:
         r = sess.result
         if use_sm:
             sm_result, cfg = r, sess.config
+        w = np.asarray(r["summary_weights"])
         in_summ = np.isin(r["summary_ids"], planted)
+        flagged = np.isin(r["summary_ids"], r["outlier_ids"])
         ids = outlier_scores(planted, r["summary_ids"], r["outlier_ids"])
         quals[label] = {
             "scored": outlier_quality(sess.score(q), truth),
             "summary_records": int(r["comm_records"]),
-            "planted_mass_in_summary": float(
-                np.asarray(r["summary_weights"])[in_summ].sum()),
+            "planted_mass_in_summary": float(w[in_summ].sum()),
+            "outlier_mass_recall": float(w[in_summ & flagged].sum()) / t,
             "id_pre_recall": ids.pre_recall, "id_recall": ids.recall}
     direct = _phase(rep, "oneshot distributed_cluster (direct)",
                     distributed_cluster,
@@ -441,20 +451,23 @@ def run_four_chips(size: Size = KDD_FOUR_CHIPS, n_sites: int = 4) -> Report:
     rep.say(f"oneshot ({planted.size} planted): "
             + json.dumps(quals, sort_keys=True))
     # Same algorithm, other summary code (fixed-shape vs host-compacted
-    # sites), so the two differ like two draws.  They are compared on what
-    # a user gets, the scored outlier decisions: the shard_map session may
-    # trail by at most three binomial standard deviations.  The id-level
-    # recall above is only reported: a sample inside an attack cluster
-    # folds its neighbours into one weighted record, which keeps their
-    # mass in the summary (and the model) but drops their ids.
-    a, b = (quals[k]["scored"] for k in ("shard_map", "simulate_coordinator"))
-    tol = 3.0 * (0.25 / max(a["planted"], 1)) ** 0.5
-    rep.check("oneshot_shard_map_vs_simulate_coordinator",
-              a["recall"] >= b["recall"] - tol
-              and a["precision"] >= b["precision"] - tol,
-              f"scored recall {a['recall']:.4f} vs {b['recall']:.4f}, "
-              f"precision {a['precision']:.4f} vs {b['precision']:.4f}, "
-              f"may trail by {tol:.4f}")
+    # sites), so the two keep different records.  A sample inside an attack
+    # cluster folds its neighbours into one weighted record, which keeps
+    # their mass but drops their ids, so id-level recall (printed above)
+    # differs between the paths without any outlier being lost.  Both are
+    # held to what that rests on: the summary keeps the planted mass, and
+    # the records k-means-- flags carry it.
+    paths = ("shard_map", "simulate_coordinator")
+    mass = {p: quals[p]["planted_mass_in_summary"] for p in paths}
+    rep.check("oneshot_planted_mass_in_summary",
+              all(abs(m - t) <= PLANTED_MASS_TOL * t for m in mass.values()),
+              ", ".join(f"{p} {m:.1f}" for p, m in mass.items())
+              + f" of t={t}, within {PLANTED_MASS_TOL:.0%}")
+    recall = {p: quals[p]["outlier_mass_recall"] for p in paths}
+    rep.check("oneshot_outlier_mass_recall",
+              all(r >= OUTLIER_MASS_RECALL_MIN for r in recall.values()),
+              ", ".join(f"{p} {r:.4f}" for p, r in recall.items())
+              + f", each >= {OUTLIER_MASS_RECALL_MIN}")
     return rep
 
 

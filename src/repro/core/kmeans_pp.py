@@ -9,13 +9,13 @@ Used in three roles:
 
 p = 2 for (k,t)-means, p = 1 for (k,t)-median.
 
-``trials > 1`` is greedy k-means++ (Arthur & Vassilvitskii 2007, §6; the
-scikit-learn default): each step draws ``trials`` candidates by D^p and
-keeps the one that lowers the weighted potential most.  Under outliers
-this matters: a far outlier is the likeliest single D^p draw once most
-clusters are covered, but it lowers the potential by its own weight only,
-while a point of an uncovered cluster lowers it by that cluster's whole
-mass.  The coordinator's k-means-- seeds this way; the k-means++ baseline
+Each step draws ``trials`` candidates by D^p and keeps the one that lowers
+the weighted potential most: ``trials=1`` is plain seeding, ``trials > 1``
+greedy k-means++ (Arthur & Vassilvitskii 2007, §6; the scikit-learn
+default).  Under outliers this matters: a far outlier is the likeliest
+single D^p draw once most clusters are covered, but it lowers the
+potential by its own weight only, while a point of an uncovered cluster
+lowers it by that cluster's whole mass.  The coordinator's k-means-- seeds this way; the k-means++ baseline
 summary keeps the paper's plain seeding (``trials=1``).
 """
 from __future__ import annotations
@@ -54,8 +54,8 @@ def kmeanspp_seed(
     metric: str = "l2sq",
     trials: int = 1,
 ):
-    """Pick ``budget`` rows of ``x`` by weighted D^p sampling (greedy over
-    ``trials`` candidates per step when ``trials > 1``).
+    """Pick ``budget`` rows of ``x`` by weighted D^p sampling, the best of
+    ``trials`` candidates per step.
 
     Returns (center indices (budget,) int32, min-dist of every point to the
     chosen set).  Zero-weight rows are never chosen.
@@ -72,15 +72,11 @@ def kmeanspp_seed(
         score = jnp.where(score.sum() > 0, score, w)
         logits = jnp.log(jnp.maximum(score, 1e-30))
         logits = jnp.where(w > 0, logits, -jnp.inf)
-        if trials == 1:
-            idx = jax.random.categorical(sk, logits).astype(jnp.int32)
-            d = _dist_to(x, x[idx], metric)
-        else:
-            cand = jax.random.categorical(sk, logits, shape=(trials,))
-            dc = jax.vmap(lambda i: _dist_to(x, x[i], metric))(cand)
-            pot = jnp.sum(w * jnp.minimum(mind, dc), axis=1)   # (trials,)
-            best = jnp.argmin(pot)
-            idx, d = cand[best].astype(jnp.int32), dc[best]
+        cand = jax.random.categorical(sk, logits, shape=(trials,))
+        dc = jax.vmap(lambda i: _dist_to(x, x[i], metric))(cand)
+        pot = jnp.sum(w * jnp.minimum(mind, dc), axis=1)   # (trials,)
+        best = jnp.argmin(pot)
+        idx, d = cand[best].astype(jnp.int32), dc[best]
         mind = jnp.minimum(mind, d)
         return (key, mind, chosen_any | True), idx
 

@@ -30,7 +30,8 @@ METRICS = ("l2sq", "l2", "l1", "cosine")
 # measured on a v5e, x2 + c2 - 2 x.c then errs by ~1.4e4 f32 ulps of
 # |x|^2 + |c|^2, enough to move argmins and the score > 1 boundary.  Every
 # distance and center-update matmul on the served path asks for full f32.
-# On the CPU this is a no-op.
+# On the CPU this is a no-op.  benchmarks/precision_probe.py measures each
+# precision on the device it runs on.
 F32 = jax.lax.Precision.HIGHEST
 
 # metrics the Pallas pdist kernel implements (see kernel.py); keep in sync

@@ -6,8 +6,8 @@ on the CPU with every check except the TPU-only ones (Pallas served every
 op, ``tpu_custom_call`` in the lowered programs, quality against the CPU
 figure); the four-chip phases run on four virtual CPU devices.  Also: the
 script refuses a CPU-only process, its host-reference check catches a
-bf16-sized distance error, and the compile-cache helper keeps the cache
-where it should.
+bf16-sized distance error, the precision probe runs, and the compile-cache
+helper keeps the cache where it should.
 """
 import importlib.util
 import json
@@ -25,6 +25,10 @@ ROOT = Path(__file__).resolve().parent.parent
 
 TINY = dict(n=6000, d=6, leaf_size=512, refresh_every=1536, ingest_batch=700,
             query_outliers=40, query_inliers=120, micro_batch=64)
+# four sites: the KDD width, and enough points per attack cluster that the
+# oneshot outlier-mass checks hold as they do at the KDD shape
+FOUR = dict(TINY, n=60_000, d=34, leaf_size=4096, refresh_every=1 << 20,
+            ingest_batch=8192)
 
 
 @pytest.fixture(scope="module")
@@ -99,9 +103,10 @@ _FOUR = textwrap.dedent("""
 """)
 
 
+@pytest.mark.slow
 def test_four_chip_rehearsal_on_virtual_devices():
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="src")
-    out = subprocess.run([sys.executable, "-c", _FOUR.format(tiny=TINY)],
+    out = subprocess.run([sys.executable, "-c", _FOUR.format(tiny=FOUR)],
                          cwd=ROOT, env=env, capture_output=True, text=True,
                          timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
@@ -111,7 +116,23 @@ def test_four_chip_rehearsal_on_virtual_devices():
         "sharded_model_on_one_device": True,
         "sharded_scores_bit_identical": True,
         "oneshot_session_vs_distributed_cluster": True,
-        "oneshot_shard_map_vs_simulate_coordinator": True}
+        "oneshot_planted_mass_in_summary": True,
+        "oneshot_outlier_mass_recall": True}
+
+
+def test_precision_probe_runs_off_the_chip(capsys):
+    """``benchmarks/precision_probe.py`` at a small size: off a TPU every
+    precision is plain f32, well inside the smoke's distance tolerance."""
+    spec = importlib.util.spec_from_file_location(
+        "precision_probe", ROOT / "benchmarks" / "precision_probe.py")
+    probe = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(probe)
+    assert probe.main(["--n", "1024"]) == 0
+    line = capsys.readouterr().out.strip().splitlines()[-1]
+    out = json.loads(line[len("PRECISION_PROBE "):])
+    for name in probe.PRECISIONS:
+        assert out[f"xla_{name}"] < 64 and out[f"pallas_{name}"] < 64
+    assert out["kernel_argmin_mismatch"] == 0
 
 
 # ------------------------------------------------------------ compile cache
