@@ -45,8 +45,9 @@ from repro.core.collective import sites_mesh
 from repro.core.distributed import distributed_cluster, simulate_coordinator
 from repro.kernels.pdist.ops import min_argmin
 from repro.serve.scheduler import ScoreTicket, ServingScheduler, ShedReject
-from repro.stream.service import (ModelState, QueryResult, ServiceConfig,
-                                  ServingFrontEnd, StreamService)
+from repro.stream.service import (ModelState, PendingFit, QueryResult,
+                                  ServiceConfig, ServingFrontEnd,
+                                  StreamService)
 from repro.stream.sharded import ShardedStreamService
 
 
@@ -103,7 +104,8 @@ class OneshotEngine(ServingFrontEnd):
             raise RuntimeError("refresh() before any point was ingested")
         x = np.concatenate(self._rows)
         self._rows = [x]          # compact the buffer while we have it
-        return functools.partial(self._fit, x, version)
+        # the coordinator takes host rows: nothing to upload ahead of it
+        return PendingFit((), functools.partial(self._fit, x, version))
 
     def _fit(self, x: np.ndarray, version: int) -> ModelState:
         res = _run_oneshot(x, self.pipeline)

@@ -11,7 +11,10 @@ Since schema v2 the plane is *explainable*, not just aggregate:
 ``trace(phase)`` spans executed under an active trace also land as
 structured ``trace_id``/``span_id``/``parent_id`` records in a bounded
 :class:`FlightRecorder` ring (export with :func:`dump_trace` — Chrome
-trace-event JSON or JSON-lines), and a :class:`~repro.obs.monitors.\
+trace-event JSON or JSON-lines, on the profiler's host clock), while a
+``jax.profiler`` session records they are written to its host plane as
+``TraceAnnotation``s, garbage-collector pauses are spans too
+(``phase.runtime.gc``), and a :class:`~repro.obs.monitors.\
 MonitorHub` of online monitors (outlier-rate drift vs the z/n budget,
 model staleness, shed burn) emits typed ``Alert`` records into
 ``snapshot()["alerts"]``.

@@ -21,8 +21,10 @@ that snapshot's home:
 * :meth:`MetricsRegistry.trace` — a ``with trace("refresh.fit"): ...``
   span recording wall time into the ``phase.refresh.fit`` histogram, the
   one idiom every pipeline phase (ingest -> leaf-flush -> merge-reduce;
-  refresh: gather -> fit -> install; score: enqueue -> batch -> fused ->
-  drain) is instrumented with.
+  refresh: gather -> fit (upload, solve) -> install; score: drain ->
+  batch -> fused; the scheduler's tick) is instrumented with.  The span
+  itself lives in ``repro.obs.tracing``: it also feeds the flight
+  recorder and, while a profiler records, the profiler's host plane.
 
 Metrics are keyed by ``name{label=value,...}`` with sorted label keys, so
 one family fans out over site id / summarizer / kernel backend / topology
@@ -44,7 +46,6 @@ import bisect
 import contextlib
 import os
 import threading
-import time
 from collections import deque
 from typing import Callable, Optional, Sequence
 
@@ -244,32 +245,47 @@ class Histogram:
         }
 
 
-class _Span:
-    __slots__ = ("_hist", "_t0")
+class GcTally:
+    """Garbage collections seen while this registry was the default.
 
-    def __init__(self, hist: Histogram):
-        self._hist = hist
+    Written only by the process's GC hook (``repro.obs.tracing``), which
+    runs inside the collector on whatever thread allocated and so may take
+    no lock that thread could hold (an allocation under a histogram's lock
+    can start a collection): it bumps a per-generation count and appends
+    the pause of a generation >= 1 collection to a bounded deque, both
+    atomic under the interpreter lock.  :meth:`fold`, under a lock of its
+    own that the hook never takes, turns them into the
+    ``runtime.gc.collections{gen=N}`` counters and ``phase.runtime.gc{gen=N}``
+    histogram when a snapshot is taken.
+    """
 
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
+    __slots__ = ("counts", "pauses", "_folded", "_lock")
 
-    def __exit__(self, *exc):
-        self._hist.observe(time.perf_counter() - self._t0)
-        return False
+    def __init__(self):
+        self.counts = [0, 0, 0]
+        self.pauses: deque = deque(maxlen=DEFAULT_RING)
+        self._folded = [0, 0, 0]
+        self._lock = threading.Lock()
 
+    def record(self, gen: int, seconds: float) -> None:
+        """The hook's call: one collection of generation ``gen``."""
+        self.counts[gen] += 1
+        if gen:
+            self.pauses.append((gen, seconds))
 
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+    def fold(self, registry: "MetricsRegistry") -> None:
+        with self._lock:
+            for gen, n in enumerate(self.counts):
+                if n > self._folded[gen]:
+                    registry.counter("runtime.gc.collections",
+                                     gen=gen).inc(n - self._folded[gen])
+                    self._folded[gen] = n
+            # those recorded by now: a collection during the fold waits
+            # for the next one
+            for _ in range(len(self.pauses)):
+                gen, seconds = self.pauses.popleft()
+                registry.histogram("phase.runtime.gc",
+                                   gen=gen).observe(seconds)
 
 
 class MetricsRegistry:
@@ -302,6 +318,10 @@ class MetricsRegistry:
             monitors = MonitorHub()
         self.recorder = recorder
         self.monitors = monitors
+        self.gc = GcTally()
+        if self.enabled:
+            from repro.obs.tracing import install_gc_hook
+            install_gc_hook()
 
     # ------------------------------------------------------------ metrics
     def counter(self, name: str, **labels) -> Counter:
@@ -333,16 +353,18 @@ class MetricsRegistry:
 
     def trace(self, phase: str, **labels):
         """``with registry.trace("refresh.fit", site=0): ...`` — wall time
-        of the block lands in the ``phase.refresh.fit{site=0}`` histogram."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self.histogram(f"phase.{phase}", **labels))
+        of the block lands in the ``phase.refresh.fit{site=0}`` histogram
+        (and the flight recorder and profiler, as ``obs.trace``)."""
+        from repro.obs.tracing import span
+        return span(self, phase, labels)
 
     # ------------------------------------------------------------ snapshot
     def snapshot(self) -> dict:
         """The ONE plain dict: every counter, gauge and histogram, keyed by
         ``name{label=value,...}``, JSON-serializable as-is.  Callable
-        gauges are evaluated here."""
+        gauges are evaluated and GC pauses folded in here."""
+        if self.enabled:
+            self.gc.fold(self)
         with self._lock:
             counters = dict(self._counters)
             gauges = dict(self._gauges)

@@ -27,17 +27,28 @@ Design points:
   admission -> queue wait -> tick -> fused score -> drain into ONE
   trace).
 
-The module-level :func:`trace` is a drop-in upgrade of the registry's
-histogram-only span: it observes the same ``phase.*`` histogram *and*
-records a flight span when called under an active sampled trace, so
-every existing ``obs.trace(...)`` call site participates in structured
-tracing with no per-site changes.
+One span, three sinks: :func:`trace` observes the ``phase.*``
+histogram, records a flight span when called under an active sampled
+trace, and, while a profiler session records (``jax.profiler.trace``),
+writes a ``jax.profiler.TraceAnnotation`` named after the phase, so the
+profiler's host plane holds the program's spans on the clock of the
+device's operations.  Exports put the recorder's spans on that clock too:
+Chrome ``ts`` is microseconds and JSON-lines ``ts`` seconds since the Unix
+epoch, the base of the profiler's host timestamps (an ``.xplane.pb``
+holds them as offsets from its ``profile_start_time``).
+
+Garbage-collector pauses are spans as well: one ``gc.callbacks`` hook per
+process (:func:`install_gc_hook`) times every collection; those of
+generation >= 1 become ``phase.runtime.gc{gen=N}`` observations and
+profiler annotations, and every collection is counted in
+``runtime.gc.collections{gen=N}``.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
 import dataclasses
+import gc
 import json
 import os
 import random
@@ -46,6 +57,8 @@ import time
 from collections import deque
 from pathlib import Path
 from typing import Any, Dict, Iterator, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs import registry as _registry
 
@@ -67,6 +80,16 @@ __all__ = [
 ]
 
 _DEFAULT_RING = 65536
+
+
+def _wall_offset() -> float:
+    """Seconds to add to a ``time.perf_counter()`` stamp to place it on the
+    profiler's host clock (the Unix epoch).  Read at export, so a wall
+    clock stepped since the spans were recorded moves them all alike."""
+    w0 = time.time()
+    p = time.perf_counter()
+    w1 = time.time()
+    return (w0 + w1) / 2 - p
 
 
 class SpanContext(NamedTuple):
@@ -114,8 +137,8 @@ class FlightRecorder:
     """Bounded in-memory ring of structured spans and instant events.
 
     Thread-safe.  All timestamps are ``time.perf_counter()`` floats;
-    export maps them to microseconds relative to the recorder's epoch
-    (Chrome) or to wall-clock seconds (JSONL).
+    export maps them to the profiler's host clock, the Unix epoch:
+    microseconds (Chrome) or seconds (JSONL).
     """
 
     def __init__(self, enabled: Optional[bool] = None, *,
@@ -142,8 +165,6 @@ class FlightRecorder:
         self._traces = 0
         self._recorded = 0
         self._dropped = 0
-        self._t0 = time.perf_counter()
-        self._wall0 = time.time()
 
     # -- identity ----------------------------------------------------------
 
@@ -323,6 +344,7 @@ class FlightRecorder:
         """
         records = self.records()
         kept = self._kept(records)
+        wall = _wall_offset()
         events: List[Dict[str, Any]] = []
         for r in kept:
             args = {
@@ -334,7 +356,7 @@ class FlightRecorder:
             args.update(r["attrs"])
             ev: Dict[str, Any] = {
                 "name": r["name"],
-                "ts": round(max(r["t0"] - self._t0, 0.0) * 1e6, 3),
+                "ts": round((r["t0"] + wall) * 1e6, 3),
                 "pid": 0,
                 "tid": r["trace_id"],
                 "args": args,
@@ -359,13 +381,15 @@ class FlightRecorder:
         }
 
     def export_jsonl(self) -> str:
-        """One JSON object per record, wall-clock timestamps."""
+        """One JSON object per record, ``ts`` in seconds since the Unix
+        epoch."""
         lines = []
+        wall = _wall_offset()
         for r in self._kept(self.records()):
             out = dict(r)
             t0 = out.pop("t0")
             t1 = out.pop("t1")
-            out["ts"] = round(self._wall0 + (t0 - self._t0), 6)
+            out["ts"] = round(t0 + wall, 6)
             out["dur_s"] = round(max(t1 - t0, 0.0), 9)
             lines.append(json.dumps(out, sort_keys=True))
         return "\n".join(lines) + ("\n" if lines else "")
@@ -409,62 +433,91 @@ def use_context(ctx: Optional[SpanContext]) -> Iterator[Optional[SpanContext]]:
 
 @contextlib.contextmanager
 def root_trace(name: str, **attrs: Any) -> Iterator[Optional[SpanContext]]:
-    """Start a new trace rooted at a span named ``name``."""
-    rec = get_default_recorder()
-    ctx = rec.new_trace()
-    if ctx is None:
-        yield None
-        return
-    token = _current.set(ctx)
-    t0 = time.perf_counter()
-    status = "ok"
-    try:
-        yield ctx
-    except BaseException as e:
-        status = "error"
-        attrs = dict(attrs)
-        attrs["error"] = type(e).__name__
-        raise
-    finally:
-        _current.reset(token)
-        rec.record_span(name, ctx, t0=t0, t1=time.perf_counter(),
-                        span_id=ctx.span_id, parent_id=None,
-                        status=status, force=status == "error",
-                        attrs=attrs)
+    """Start a new trace rooted at a span named ``name`` (annotated on the
+    profiler like any span)."""
+    with _annotation(name, attrs):
+        rec = get_default_recorder()
+        ctx = rec.new_trace()
+        if ctx is None:
+            yield None
+            return
+        token = _current.set(ctx)
+        t0 = time.perf_counter()
+        status = "ok"
+        try:
+            yield ctx
+        except BaseException as e:
+            status = "error"
+            attrs = dict(attrs)
+            attrs["error"] = type(e).__name__
+            raise
+        finally:
+            _current.reset(token)
+            rec.record_span(name, ctx, t0=t0, t1=time.perf_counter(),
+                            span_id=ctx.span_id, parent_id=None,
+                            status=status, force=status == "error",
+                            attrs=attrs)
 
 
-class _DualSpan:
-    """Span that feeds both the phase histogram and the flight recorder.
+class _NullSpan:
+    __slots__ = ()
 
-    Installs itself as the current context so nested ``trace()`` calls
-    parent correctly.
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL_SPAN = _NullSpan()
+
+
+def _annotation(name: str, labels: Dict[str, Any]):
+    """The profiler's host annotation for a span, while a profiler session
+    records; a no-op otherwise (one ``is_enabled`` check)."""
+    if TraceAnnotation.is_enabled():
+        return TraceAnnotation(name, **labels)
+    return _NULL_SPAN
+
+
+class _Span:
+    """One span: the phase histogram (when metrics are on), the flight
+    recorder (under a sampled trace) and the profiler (while it records).
+
+    Under a sampled trace it installs itself as the current context so
+    nested ``trace()`` calls parent correctly.
     """
 
-    __slots__ = ("_reg", "_rec", "_outer", "_name", "_labels",
-                 "_ctx", "_token", "_t0")
+    __slots__ = ("_hist", "_rec", "_outer", "_name", "_labels",
+                 "_ctx", "_token", "_ann", "_t0")
 
-    def __init__(self, reg: "_registry.MetricsRegistry",
-                 rec: FlightRecorder, outer: SpanContext,
+    def __init__(self, hist: Optional["_registry.Histogram"],
+                 rec: Optional[FlightRecorder], outer: Optional[SpanContext],
                  name: str, labels: Dict[str, Any]) -> None:
-        self._reg = reg
+        self._hist = hist
         self._rec = rec
         self._outer = outer
         self._name = name
         self._labels = labels
 
-    def __enter__(self) -> "_DualSpan":
-        self._ctx = SpanContext(self._outer.trace_id, self._rec.alloc_id(),
-                                True)
-        self._token = _current.set(self._ctx)
+    def __enter__(self) -> "_Span":
+        if self._rec is not None:
+            self._ctx = SpanContext(self._outer.trace_id,
+                                    self._rec.alloc_id(), True)
+            self._token = _current.set(self._ctx)
+        self._ann = _annotation(self._name, self._labels)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
+        if self._hist is not None:
+            self._hist.observe(t1 - self._t0)
+        if self._rec is None:
+            return False
         _current.reset(self._token)
-        if self._reg.enabled:
-            self._reg.histogram(f"phase.{self._name}",
-                                **self._labels).observe(t1 - self._t0)
         attrs = dict(self._labels)
         status = "ok"
         if exc_type is not None:
@@ -478,18 +531,76 @@ class _DualSpan:
         return False
 
 
-def trace(phase: str, **labels: Any):
-    """Combined histogram + flight-recorder span.
-
-    Outside an active sampled trace this degrades to the registry's
-    histogram-only span (one contextvar read of extra cost), so the
-    hot path stays within the obs overhead budget.
-    """
-    reg = _registry.get_default_registry()
+def span(reg: "_registry.MetricsRegistry", phase: str,
+         labels: Dict[str, Any]):
+    """A span of ``phase`` on registry ``reg`` (see :func:`trace`)."""
+    hist = (reg.histogram(f"phase.{phase}", **labels) if reg.enabled
+            else None)
     ctx = _current.get()
-    if ctx is not None and ctx.sampled and reg.recorder.enabled:
-        return _DualSpan(reg, reg.recorder, ctx, phase, labels)
-    return reg.trace(phase, **labels)
+    rec = reg.recorder
+    if not (ctx is not None and ctx.sampled and rec.enabled):
+        rec = None
+        if hist is None and not TraceAnnotation.is_enabled():
+            return _NULL_SPAN
+    return _Span(hist, rec, ctx, phase, labels)
+
+
+def trace(phase: str, **labels: Any):
+    """``with trace("refresh.fit", topology="stream"): ...`` — one span.
+
+    Its wall time lands in the ``phase.refresh.fit{topology=stream}``
+    histogram; under an active sampled trace it is also a flight-recorder
+    span, and while a profiler session records, a host annotation named
+    ``refresh.fit`` with the labels as its arguments.  Outside both it
+    costs a histogram observation and one ``is_enabled`` check.
+    """
+    return span(_registry.get_default_registry(), phase, labels)
+
+
+# -- garbage-collector pauses -------------------------------------------------
+
+class _GcHook:
+    """The process's ``gc.callbacks`` entry: times each collection.
+
+    It runs inside the collector, on whichever thread allocated, so it
+    takes no lock that thread may hold: the pause goes to the default
+    registry's :class:`~repro.obs.registry.GcTally` lock-free and is
+    folded into metrics at the next snapshot.  Collections do not nest,
+    so one start stamp is enough.
+    """
+
+    def __init__(self) -> None:
+        self._t0 = 0.0
+        self._ann: Any = _NULL_SPAN
+
+    def __call__(self, phase: str, info: Dict[str, Any]) -> None:
+        gen = info["generation"]
+        if phase == "start":
+            self._ann = (_annotation("runtime.gc", {"gen": gen}) if gen
+                         else _NULL_SPAN)
+            self._ann.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(None, None, None)
+        self._ann = _NULL_SPAN
+        reg = _registry.get_default_registry()
+        if reg.enabled:
+            reg.gc.record(gen, seconds)
+
+
+_gc_hook_lock = threading.Lock()
+
+
+def install_gc_hook() -> None:
+    """Put the one GC-pause hook in ``gc.callbacks`` (idempotent).
+
+    Metrics registries that start enabled call this, so a process with
+    ``REPRO_METRICS=0`` never has it.
+    """
+    with _gc_hook_lock:
+        if not any(isinstance(cb, _GcHook) for cb in gc.callbacks):
+            gc.callbacks.append(_GcHook())
 
 
 # -- default-recorder front door --------------------------------------------

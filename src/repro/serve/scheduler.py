@@ -33,8 +33,9 @@ Telemetry (``repro.obs``): ``serve.queue_depth`` gauge,
 ``serve.admitted{tenant=}`` / ``serve.completed{tenant=}`` /
 ``serve.shed{tenant=,reason=}`` counters, ``serve.batch_occupancy``
 histogram (batched rows / max_batch per tick), ``serve.ticks`` counter,
-and per-tenant end-to-end latency in
-``serve.latency{tenant=,topology=scheduler}``.
+``phase.serve.tick`` (pop of the batch to its last ticket resolved) and
+``phase.serve.linger`` (the batch-window wait) spans, and per-tenant
+end-to-end latency in ``serve.latency{tenant=,topology=scheduler}``.
 
 Tracing: every submitted row starts a trace in the flight recorder;
 its lifecycle spans (``serve.request`` root, ``serve.admission``,
@@ -353,12 +354,17 @@ class ServingScheduler:
                 # continuous batching: linger up to the batch window so
                 # requests arriving from other clients join this tick
                 if window_s > 0 and len(self._queue) < self.max_batch:
-                    deadline = time.perf_counter() + window_s
-                    while len(self._queue) < self.max_batch:
-                        remaining = deadline - time.perf_counter()
-                        if remaining <= 0 or self._stop:
-                            break
-                        self._cond.wait(remaining)
+                    with obs.trace("serve.linger"):
+                        deadline = time.perf_counter() + window_s
+                        while len(self._queue) < self.max_batch:
+                            remaining = deadline - time.perf_counter()
+                            if remaining <= 0 or self._stop:
+                                break
+                            self._cond.wait(remaining)
+                # the tick's span runs from the pop to its last ticket
+                # resolved; it opens under the lock, so it ends in finally
+                tick = obs.trace("serve.tick")
+                tick.__enter__()
                 take = min(self.max_batch, len(self._queue))
                 batch = [self._queue.popleft() for _ in range(take)]
                 t_pop = time.perf_counter()
@@ -370,6 +376,7 @@ class ServingScheduler:
             try:
                 self._score_batch(batch)
             finally:
+                tick.__exit__(None, None, None)
                 with self._cond:
                     self._inflight -= len(batch)
                     self._cond.notify_all()
@@ -383,8 +390,8 @@ class ServingScheduler:
         self._occupancy.observe(len(batch) / self.max_batch)
         rows = np.stack([row for _, row in batch])
         # cross-thread stitch: carry the first sampled ticket's trace into
-        # the engine work so its score.enqueue/batch/fused/drain spans nest
-        # under this tick (one "primary" per tick keeps the worker O(1))
+        # the engine work so its score.drain/batch/fused spans nest under
+        # this tick (one "primary" per tick keeps the worker O(1))
         rec = self._recorder
         primary: Optional[ScoreTicket] = None
         tick_span_id: Optional[int] = None

@@ -130,6 +130,17 @@ class FitStats(NamedTuple):
     installed_at: float
 
 
+class PendingFit(NamedTuple):
+    """A refresh's fit with its inputs snapshotted on the calling thread.
+
+    ``inputs`` are arrays already handed to the device (their upload may
+    still be in flight); ``solve(*inputs)`` computes the ``ModelState``.
+    Keeping them apart lets the fit span tell the upload from the solve.
+    """
+    inputs: tuple
+    solve: Callable[..., "ModelState"]
+
+
 class QueryResult(NamedTuple):
     request_id: int
     center: int              # nearest-center index
@@ -175,7 +186,7 @@ class ServingFrontEnd:
     """Micro-batched read path + double-buffered model state.
 
     Subclasses own the write path and provide ``_fit_closure(version)``: a
-    zero-arg callable, with all inputs already snapshotted on the calling
+    :class:`PendingFit`, with all inputs already snapshotted on the calling
     thread, that computes the next ``ModelState``.  The front end decides
     *when* it runs (inline for blocking refreshes, on a worker thread for
     async ones) and installs the result.
@@ -184,7 +195,8 @@ class ServingFrontEnd:
     ``serve.latency{topology=...}`` histogram in the process metrics
     registry (fixed buckets + recent-sample ring — a long-running service
     holds O(1) latency state, unlike the unbounded list this replaced);
-    refresh phases are traced (``phase.refresh.gather|fit|install``); the
+    refresh phases are traced (``phase.refresh.gather|fit|install``, the
+    fit split into ``refresh.upload`` and ``refresh.solve``); the
     last installed refresh is summarized in ``last_fit`` (:class:`FitStats`)
     with a live ``model.seconds_since_install`` staleness gauge.  Metrics
     are keyed per *topology*, so two services of the same class in one
@@ -262,7 +274,7 @@ class ServingFrontEnd:
         self.refresh(blocking=not self.cfg.async_refresh)
 
     # ------------------------------------------------------------ refresh
-    def _fit_closure(self, version: int) -> Optional[Callable[[], ModelState]]:
+    def _fit_closure(self, version: int) -> Optional[PendingFit]:
         """Snapshot the root and return the deferred fit — or None to skip
         (incremental refresh proved the installed model is already it)."""
         raise NotImplementedError
@@ -271,13 +283,18 @@ class ServingFrontEnd:
         """Live root records a refresh fits on (telemetry only)."""
         return 0
 
-    def _timed_fit(self, fit: Callable[[], ModelState]):
-        """Run the fit, fully materialized, under the fit-phase span.
+    def _timed_fit(self, fit: PendingFit):
+        """Run the fit, fully materialized, under the fit-phase span: first
+        until its inputs are resident on the device (``refresh.upload``),
+        then the solve until the model is ready (``refresh.solve``).
         Returns (model, fit wall seconds)."""
         t0 = time.perf_counter()
         with obs.trace("refresh.fit", topology=self._topology):
-            model = fit()
-            jax.block_until_ready(model)
+            with obs.trace("refresh.upload", topology=self._topology):
+                jax.block_until_ready(fit.inputs)
+            with obs.trace("refresh.solve", topology=self._topology):
+                model = fit.solve(*fit.inputs)
+                jax.block_until_ready(model)
         return model, time.perf_counter() - t0
 
     def _install(self, model: ModelState, fit_s: float,
@@ -436,13 +453,11 @@ class ServingFrontEnd:
         # reaches drain() would crash mid-batch after requests were
         # already dequeued
         x, _ = self._validate_points(points, None)
-        now = time.perf_counter()
-        with obs.trace("score.enqueue", topology=self._topology):
-            n = x.shape[0]
-            ids = list(range(self._next_id, self._next_id + n))
-            self._queue.append((self._next_id, x, now))
-            self._queued_rows += n
-            self._next_id += n
+        n = x.shape[0]
+        ids = list(range(self._next_id, self._next_id + n))
+        self._queue.append((self._next_id, x, time.perf_counter()))
+        self._queued_rows += n
+        self._next_id += n
         obs.counter("score.requests", topology=self._topology).inc(len(ids))
         return ids
 
@@ -622,10 +637,12 @@ class StreamService(ServingFrontEnd):
         else:
             key = jax.random.fold_in(self._model_key, version)
         pts, wts, valid = self.tree.packed_root()
-        return functools.partial(
-            fit_model, jnp.asarray(pts), jnp.asarray(wts), jnp.asarray(valid),
-            key, version, k=cfg.k, t=cfg.t, iters=cfg.second_iters,
-            metric=cfg.metric, policy=cfg.policy, init_centers=init)
+        return PendingFit(
+            (jnp.asarray(pts), jnp.asarray(wts), jnp.asarray(valid)),
+            functools.partial(
+                fit_model, key=key, version=version, k=cfg.k, t=cfg.t,
+                iters=cfg.second_iters, metric=cfg.metric,
+                policy=cfg.policy, init_centers=init))
 
     # ------------------------------------------------------------ checkpoint
     def _state(self) -> dict:

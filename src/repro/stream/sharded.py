@@ -59,7 +59,7 @@ from repro.core.collective import (gather_sites, gathered_bytes,
                                    sites_mesh)
 from repro.core.distributed import local_budget
 from repro.stream.service import (BaseServiceConfig, ModelState,
-                                  ServingFrontEnd, fit_model)
+                                  PendingFit, ServingFrontEnd, fit_model)
 from repro.stream.tree import StreamTree, TreeConfig
 from repro.stream.weighted import _bucket
 
@@ -263,16 +263,19 @@ class ShardedStreamService(ServingFrontEnd):
             # host-sim: concatenation in site order is exactly what the
             # collective would deliver to every participant
             s, r, d = pts.shape
-            return functools.partial(
-                fit_model, jnp.asarray(pts.reshape(s * r, d)),
-                jnp.asarray(wts.reshape(s * r)),
-                jnp.asarray(val.reshape(s * r)), key, version, k=cfg.k,
-                t=cfg.t, iters=cfg.second_iters, metric=cfg.metric,
-                policy=cfg.policy, init_centers=init)
+            return PendingFit(
+                (jnp.asarray(pts.reshape(s * r, d)),
+                 jnp.asarray(wts.reshape(s * r)),
+                 jnp.asarray(val.reshape(s * r))),
+                functools.partial(
+                    fit_model, key=key, version=version, k=cfg.k, t=cfg.t,
+                    iters=cfg.second_iters, metric=cfg.metric,
+                    policy=cfg.policy, init_centers=init))
 
         program = self._gathered_program()
         triple = (jnp.asarray(pts), jnp.asarray(wts), jnp.asarray(val))
-        return lambda: program(triple, key, np.int32(version))
+        return PendingFit((triple,),
+                          lambda tr: program(tr, key, np.int32(version)))
 
     # ------------------------------------------------------------ aggregates
     @property

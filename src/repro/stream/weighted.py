@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels.dispatch import KernelPolicy, resolve_policy
 from repro.kernels.pdist.ops import min_argmin
 
@@ -65,6 +66,7 @@ def categorical_by_weight(key: jax.Array, w: np.ndarray, shape) -> np.ndarray:
     """
     logits = np.full((_bucket(w.size),), -np.inf, np.float32)
     logits[:w.size] = np.log(w)
+    obs.counter("summary.h2d_bytes").inc(logits.nbytes)
     return np.asarray(jax.random.categorical(key, jnp.asarray(logits),
                                              shape=shape))
 
@@ -73,12 +75,15 @@ def _min_argmin_bucketed(xr: np.ndarray, c: np.ndarray, *, metric: str,
                          policy: Optional[KernelPolicy]):
     """min_argmin with the row count padded to a power-of-two bucket, so the
     jitted kernel compiles once per bucket instead of once per round (the
-    remaining set shrinks every round and would otherwise retrace)."""
+    remaining set shrinks every round and would otherwise retrace).  The
+    padded rows and the centers sent to the device are counted in
+    ``summary.h2d_bytes``."""
     nr = xr.shape[0]
     nb = _bucket(nr)
     if nb > nr:
         xr = np.concatenate(
             [xr, np.full((nb - nr, xr.shape[1]), _FAR, np.float32)])
+    obs.counter("summary.h2d_bytes").inc(xr.nbytes + np.asarray(c).nbytes)
     mind, amin = min_argmin(xr, c, metric=metric, policy=policy)
     return np.asarray(mind)[:nr], np.asarray(amin)[:nr]
 
@@ -167,6 +172,7 @@ def weighted_summary_outliers(
         center_ids.append(np.unique(idx))
         remaining = remaining[~captured]
         rounds += 1
+    obs.counter("summary.rounds").inc(rounds)
 
     centers = (np.unique(np.concatenate(center_ids)) if center_ids
                else np.empty(0, np.int64))

@@ -33,6 +33,7 @@ import math
 import numpy as np
 import jax
 
+from repro import obs
 from repro.summarize.base import (clean_weighted_input, empty_summary,
                                   register_summarizer)
 
@@ -90,6 +91,7 @@ def _summarize(points, weights, key, *, k, t, alpha, beta, metric,
             center_ids.append(np.unique(idx))
         remaining = remaining[~captured]
         rounds += 1
+    obs.counter("summary.rounds").inc(rounds)
 
     centers = (np.unique(np.concatenate(center_ids)) if center_ids
                else np.empty(0, np.int64))

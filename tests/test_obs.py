@@ -172,6 +172,85 @@ def test_trace_span_records_wall_time():
     assert e["count"] == 1 and e["sum"] >= 0
 
 
+def test_trace_without_profiler_records_histogram_as_before():
+    from jax.profiler import TraceAnnotation
+    from repro.obs.tracing import _NULL_SPAN
+    assert not TraceAnnotation.is_enabled()
+    with obs.using_registry(obs.MetricsRegistry()) as reg:
+        for _ in range(3):
+            with obs.trace("step", topology="t"):
+                pass
+        e = reg.snapshot()["histograms"]["phase.step{topology=t}"]
+        assert e["count"] == 3 and e["sum"] >= 0
+        assert reg.recorder.spans() == []   # no trace: no flight record
+    # metrics off, no trace and no profiler: the span does nothing at all
+    assert obs.MetricsRegistry(enabled=False).trace("p") is _NULL_SPAN
+
+
+def test_gc_collect_adds_one_gen2_observation():
+    import gc
+    with obs.using_registry(obs.MetricsRegistry()) as reg:
+        def gen2():
+            snap = reg.snapshot()
+            return (snap["histograms"].get("phase.runtime.gc{gen=2}",
+                                           {"count": 0})["count"],
+                    snap["counters"].get("runtime.gc.collections{gen=2}", 0))
+        before = gen2()
+        gc.collect()
+        after = gen2()
+    assert after == (before[0] + 1, before[1] + 1)
+    e = reg.snapshot()["histograms"]["phase.runtime.gc{gen=2}"]
+    assert e["sum"] > 0
+
+
+def test_gc_hook_completes_a_collection_started_inside_observe():
+    """A collection that starts while ``Histogram.observe`` holds its lock
+    runs the hook to the end: the hook takes no registry lock."""
+    import gc
+
+    class CollectingRing(list):
+        def append(self, v):
+            super().append(v)
+            if len(self) == 1:
+                gc.collect()
+
+    with obs.using_registry(obs.MetricsRegistry()) as reg:
+        reg.snapshot()
+        h = reg.histogram("phase.runtime.gc", gen=2)
+        h._ring = CollectingRing()
+        done = []
+
+        def observe():
+            with reg._lock:          # the registry's lock is held too
+                h.observe(0.5)
+            done.append(True)
+
+        th = threading.Thread(target=observe, daemon=True)
+        th.start()
+        th.join(timeout=60.0)
+        assert not th.is_alive() and done
+        e = reg.snapshot()["histograms"]["phase.runtime.gc{gen=2}"]
+        assert e["count"] == 2      # the 0.5 observed, then the collection
+        assert reg.snapshot()["counters"][
+            "runtime.gc.collections{gen=2}"] >= 1
+
+
+def test_summary_flush_counts_rounds_and_device_bytes():
+    from repro.stream.tree import StreamTree, TreeConfig
+    from repro.stream.weighted import max_rounds
+    with obs.using_registry(obs.MetricsRegistry()) as reg:
+        tree = StreamTree(TreeConfig(dim=4, k=3, t=5, leaf_size=512))
+        tree.ingest(_ingest_data(n=512))
+        c = reg.snapshot()["counters"]
+    assert max_rounds(512, 5, 0.45) >= 1
+    assert sum(v for k, v in c.items()
+               if k.startswith("tree.leaf_flushes")) == 1
+    assert c["summary.rounds"] >= 1
+    # each round sends at least its padded rows (a 256-row bucket of d=4
+    # float32) to the device
+    assert c["summary.h2d_bytes"] >= c["summary.rounds"] * 256 * 4 * 4
+
+
 def test_using_registry_scopes_default():
     base = obs.get_default_registry()
     with obs.using_registry(obs.MetricsRegistry()) as reg:

@@ -319,9 +319,11 @@ def test_sharded_refresh_stitches_site_roots_under_one_trace():
         assert len(sites) == cfg.n_sites
         assert {s["attrs"]["site"] for s in sites} == set(range(cfg.n_sites))
         assert all(s["trace_id"] == tid for s in sites)
-        for name in ("refresh.gather", "refresh.fit", "refresh.install"):
+        for name in ("refresh.gather", "refresh.fit", "refresh.install",
+                     "refresh.upload", "refresh.solve"):
             got = rec.spans(name)
             assert got and all(s["trace_id"] == tid for s in got), name
+        _assert_fit_split(rec, tid)
         check_trace = _load_bench("check_trace")
         assert check_trace.validate_trace(rec.export_chrome()) == []
 
@@ -341,6 +343,89 @@ def test_async_refresh_carries_trace_across_fit_worker():
         installs = [s for s in reg.recorder.spans("refresh.install")
                     if s["trace_id"] == tid]
         assert fits and installs   # worker thread + poller both stitched
+        _assert_fit_split(reg.recorder, tid)
+
+
+def _assert_fit_split(rec, tid):
+    """The fit of trace ``tid`` holds one upload, then one solve."""
+    (fit,) = [s for s in rec.spans("refresh.fit") if s["trace_id"] == tid]
+    (up,) = [s for s in rec.spans("refresh.upload") if s["trace_id"] == tid]
+    (solve,) = [s for s in rec.spans("refresh.solve")
+                if s["trace_id"] == tid]
+    assert up["parent_id"] == solve["parent_id"] == fit["span_id"]
+    assert fit["t0"] <= up["t0"] <= up["t1"] <= solve["t0"] \
+        <= solve["t1"] <= fit["t1"]
+
+
+# ------------------------------------------------------------ profiler clock
+def _host_events(logdir) -> list[tuple[str, int, int]]:
+    """(name, start, end) of every host event of the profile under
+    ``logdir``, in ns since the Unix epoch."""
+    from jax.profiler import ProfileData
+    (path,) = Path(logdir).glob("**/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    env = pd.find_plane_with_name("Task Environment")
+    start = dict(env.stats)["profile_start_time"]
+    return [(e.name, start + e.start_ns, start + e.end_ns)
+            for p in pd.planes if p.name.startswith("/host:")
+            for ln in p.lines for e in ln.events]
+
+
+def test_refresh_spans_land_on_the_profiler_host_plane(tmp_path):
+    import jax
+    with obs.using_registry(obs.MetricsRegistry()):
+        svc = _fitted_service()
+        with jax.profiler.trace(str(tmp_path)):
+            svc.refresh()
+    events = {}
+    for name, t0, t1 in _host_events(tmp_path):
+        events.setdefault(name, []).append((t0, t1))
+    names = ("refresh", "refresh.gather", "refresh.fit", "refresh.upload",
+             "refresh.solve", "refresh.install")
+    got = {n: events.get(n, []) for n in names}
+    assert all(len(v) == 1 for v in got.values()), got
+    (fit,), (up,), (solve,) = (got["refresh.fit"], got["refresh.upload"],
+                               got["refresh.solve"])
+    assert fit[0] <= up[0] <= up[1] <= solve[0] <= solve[1] <= fit[1]
+    (root,), (gather,) = got["refresh"], got["refresh.gather"]
+    assert root[0] <= gather[0] <= gather[1] <= fit[0] <= fit[1] <= root[1]
+
+
+def test_exported_spans_share_the_profiler_clock(tmp_path):
+    import jax
+    with obs.using_registry(obs.MetricsRegistry()) as reg:
+        with jax.profiler.trace(str(tmp_path)):
+            with obs.root_trace("clock.root"):
+                with obs.trace("clock.span"):
+                    pass
+        doc = reg.recorder.export_chrome()
+        lines = [json.loads(line)
+                 for line in reg.recorder.export_jsonl().splitlines()]
+    ((_, t0, _),) = [e for e in _host_events(tmp_path)
+                     if e[0] == "clock.span"]
+    (chrome,) = [e for e in doc["traceEvents"] if e["name"] == "clock.span"]
+    (jl,) = [r for r in lines if r["name"] == "clock.span"]
+    assert abs(chrome["ts"] * 1e3 - t0) < 1e6      # within 1 ms
+    assert abs(jl["ts"] * 1e9 - t0) < 1e6
+
+
+def test_scheduler_tick_span_per_tick():
+    with obs.using_registry(obs.MetricsRegistry()) as reg:
+        svc = _fitted_service()
+        sched = ServingScheduler(svc, ServingSpec(queue_bound=256,
+                                                  batch_window_ms=1.0))
+        for i in range(4):
+            for t in sched.submit(_cluster_data(n=24, seed=20 + i)):
+                t.result(timeout=60.0)
+        sched.close()
+        snap = reg.snapshot()
+    ticks = snap["counters"]["serve.ticks"]
+    tick = snap["histograms"]["phase.serve.tick"]
+    drain = snap["histograms"]["phase.score.drain{topology=stream}"]
+    assert ticks >= 4 and tick["count"] == ticks
+    assert snap["histograms"]["phase.serve.linger"]["count"] >= 1
+    # the tick holds the drain it makes
+    assert tick["sum"] >= drain["sum"]
 
 
 # ------------------------------------------------------------ monitors
