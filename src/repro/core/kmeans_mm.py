@@ -42,11 +42,20 @@ class OutlierClustering(NamedTuple):
 
 def _mark_outliers(dist, w_eff, t):
     """Greedy farthest-first: True for records whose cumulative weight
-    (in decreasing-distance order) stays within the budget t."""
-    order = jnp.argsort(-dist)
-    cumw = jnp.cumsum(w_eff[order])
-    out_sorted = (cumw <= t) & (w_eff[order] > 0)
-    return jnp.zeros_like(out_sorted).at[order].set(out_sorted)
+    (in decreasing-distance order, ties by index) stays within the budget t.
+
+    Sorts only, no gather or scatter: on the TPU those two lower to slow
+    per-element fusions.  The weights ride the sort on (-dist, index), a
+    total order that is the stable argsort's; a second sort of
+    ``2 * index + mask`` (int32, so n < 2**30) puts the mask back in record
+    order."""
+    iota = jax.lax.iota(jnp.int32, dist.shape[0])
+    _, order, w_sorted = jax.lax.sort((-dist, iota, w_eff), num_keys=2,
+                                      is_stable=False)
+    out_sorted = (jnp.cumsum(w_sorted) <= t) & (w_sorted > 0)
+    packed = jax.lax.sort(2 * order + out_sorted.astype(jnp.int32),
+                          is_stable=False)
+    return (packed & 1).astype(bool)
 
 
 def kmeans_minus_minus(

@@ -10,8 +10,8 @@ try:  # optional: only the property tests need hypothesis
 except ImportError:
     HAVE_HYPOTHESIS = False
 
-from repro.core import (kmeans_minus_minus, kmeanspp_summary, pp_budget,
-                        kmeans_parallel_summary, rand_summary)
+from repro.core import (kmeans_minus_minus, kmeans_mm, kmeanspp_summary,
+                        pp_budget, kmeans_parallel_summary, rand_summary)
 from repro.data.synthetic import gauss
 
 
@@ -91,3 +91,84 @@ if HAVE_HYPOTHESIS:
 else:
     def test_kmeans_mm_property():
         pytest.importorskip("hypothesis")
+
+
+# ----- outlier marking: sorts only, bit-identical to argsort+gather+scatter --
+
+
+def _mark_outliers_gather_scatter(dist, w_eff, t):
+    """The argsort + gather + scatter marking the sort-only one replaced."""
+    order = jnp.argsort(-dist)
+    cumw = jnp.cumsum(w_eff[order])
+    out_sorted = (cumw <= t) & (w_eff[order] > 0)
+    return jnp.zeros_like(out_sorted).at[order].set(out_sorted)
+
+
+def _marking_case(n, weights, seed):
+    """Distances with exact ties (few distinct values, duplicated records)
+    and -inf padding rows; weights with zeros, whole or fractional."""
+    rng = np.random.default_rng(seed)
+    dist = rng.integers(0, max(2, n // 8), size=n).astype(np.float32)
+    dist[rng.random(n) < 0.1] = -np.inf
+    if weights == "whole":
+        w = rng.integers(0, 4, size=n).astype(np.float32)
+    else:
+        w = rng.uniform(0.0, 2.0, size=n).astype(np.float32)
+        w[rng.random(n) < 0.2] = 0.0
+    return jnp.asarray(dist), jnp.asarray(w)
+
+
+@pytest.mark.parametrize("budget", ["zero", "boundary", "inside", "total"])
+@pytest.mark.parametrize("weights", ["whole", "fractional"])
+@pytest.mark.parametrize("n", [1, 7, 4096, 70000])
+def test_mark_outliers_matches_gather_scatter(n, weights, budget):
+    dist, w = _marking_case(n, weights, seed=n)
+    order = jnp.argsort(-dist)
+    cumw = np.asarray(jnp.cumsum(w[order]))
+    t = {"zero": 0.0,
+         "boundary": cumw[n // 2],            # exactly a cumulative weight
+         "inside": 0.37 * float(cumw[-1]),
+         "total": float(cumw[-1]) + 1.0}[budget]
+    t = jnp.float32(t)
+    got = jax.jit(kmeans_mm._mark_outliers)(dist, w, t)
+    want = jax.jit(_mark_outliers_gather_scatter)(dist, w, t)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_mark_outliers_lowers_without_gather_or_scatter():
+    n = 4096
+    args = (jnp.zeros((n,), jnp.float32), jnp.ones((n,), jnp.float32),
+            jnp.float32(8.0))
+    old = jax.jit(_mark_outliers_gather_scatter).lower(*args).as_text()
+    assert "stablehlo.gather" in old and "stablehlo.scatter" in old
+    new = jax.jit(kmeans_mm._mark_outliers).lower(*args).as_text()
+    assert "stablehlo.gather" not in new
+    assert "stablehlo.scatter" not in new
+
+
+def test_kmeans_mm_output_unchanged_by_sort_only_marking(monkeypatch):
+    """The whole solve (centers, assignment, outliers, cost) is bit-identical
+    whichever marking the Lloyd loop traces."""
+    x, _ = gauss(n_centers=4, per_center=300, t=30, sigma=0.1, seed=5)
+    n = x.shape[0]
+    w = np.random.default_rng(5).integers(1, 4, size=n).astype(np.float32)
+    valid = np.ones((n,), bool)
+    valid[-17:] = False
+
+    def solve():
+        jax.clear_caches()   # retrace with the module's current marking
+        return kmeans_minus_minus(jnp.asarray(x), jnp.asarray(w),
+                                  jnp.asarray(valid), jax.random.key(3),
+                                  k=4, t=30.0, iters=12)
+
+    new = solve()
+    monkeypatch.setattr(kmeans_mm, "_mark_outliers",
+                        _mark_outliers_gather_scatter)
+    old = solve()
+    monkeypatch.undo()
+    jax.clear_caches()
+    assert int(np.asarray(old.outlier).sum()) > 0
+    for field in ("centers", "assignment", "outlier", "cost"):
+        np.testing.assert_array_equal(np.asarray(getattr(new, field)),
+                                      np.asarray(getattr(old, field)))

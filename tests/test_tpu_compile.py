@@ -7,7 +7,8 @@ chip of a described ``v5e:2x2`` topology (no chip attached) and looks for
 the Mosaic kernel (``tpu_custom_call``) in the compiled program.  Widths:
 the KDD smoke's (d=34, m=3 and m=20 centers) and the W1 vector-index
 coarse quantizer's (d=128, m=4096 centers), for every metric each kernel
-implements (the lloyd kernel has no l1 path).
+implements (the lloyd kernel has no l1 path).  One more case compiles
+k-means--'s outlier marking and checks it holds no gather or scatter.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every pytest worker imports
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.kmeans_mm import _mark_outliers
 from repro.kernels.lloyd.kernel import lloyd_step_pallas
 from repro.kernels.pdist.kernel import min_argmin_pallas
 from repro.kernels.score.kernel import score_pallas
@@ -72,3 +74,16 @@ def test_kernel_compiles_for_v5e(one_chip, op, metric, shape):
         args = (spec(n, d), spec(n), spec(m, d))
     compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_outlier_marking_compiles_without_gather_or_scatter(one_chip):
+    """k-means--'s outlier marking compiles for the chip to sorts and
+    elementwise ops only: a gather or a scatter there lowers to slow
+    per-element fusions.  (4,096 records: the TPU compiler takes minutes
+    over a sort of the ingest roots' 2**18-2**19.)"""
+    n = 4096
+    spec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    t = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    text = jax.jit(_mark_outliers).lower(spec, spec, t).compile().as_text()
+    assert " sort(" in text
+    assert " gather(" not in text and " scatter(" not in text
