@@ -401,12 +401,15 @@ def run_four_chips(size: Size = KDD_FOUR_CHIPS, n_sites: int = 4) -> Report:
                 f"{st.comm_records} records gathered, {st.comm_bytes} bytes")
         runs[label] = (model, res, st)
     (m1, r1, s1), (m2, r2, s2) = runs["shard_map"], runs["host-sim"]
-    same_model = all(np.array_equal(np.asarray(a), np.asarray(b))
-                     for a, b in zip(m1, m2))
+    differ = [f"{f} {np.asarray(a).tolist()} vs {np.asarray(b).tolist()}"
+              if np.asarray(a).size == 1 else f
+              for f, a, b in zip(m1._fields, m1, m2)
+              if not np.array_equal(np.asarray(a), np.asarray(b))]
     rep.check("sharded_shard_map_path", s1.path == "shard_map"
               and s2.path == "host-sim", f"{s1.path} vs {s2.path}")
-    rep.check("sharded_model_bit_identical", same_model,
-              "centers, threshold, cost, version, trained weight")
+    rep.check("sharded_model_bit_identical", not differ,
+              "centers, threshold, cost, version, trained weight"
+              + (f"; differ: {', '.join(differ)}" if differ else ""))
     rep.check("sharded_model_on_one_device",
               all(len(a.sharding.device_set) == 1 for a in m1),
               "the gathered model feeds single-device score programs")

@@ -9,6 +9,8 @@ streaming path (``repro.stream.sharded``) both follow it; this module holds
 the plumbing they would otherwise duplicate:
 
 * ``sites_mesh``         — the canonical 1-D mesh over ``sites``;
+* ``put_sites``          — lay per-site host payloads out over the mesh,
+                           each site's straight onto its own device;
 * ``gather_sites``       — all_gather a pytree over the axis and collapse
                            the site dim, i.e. "send every site's summary to
                            the coordinator" as one collective;
@@ -26,7 +28,7 @@ import math
 
 import jax
 import numpy as np
-from jax.sharding import AxisType, Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def sites_mesh(n_sites: int | None = None, *, axis: str = "sites") -> Mesh:
@@ -39,6 +41,30 @@ def sites_mesh(n_sites: int | None = None, *, axis: str = "sites") -> Mesh:
     n = n_sites or len(jax.devices())
     return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,),
                          devices=jax.devices()[:n])
+
+
+def put_sites(per_site, mesh: Mesh, *, axis: str = "sites"):
+    """Site-stacked device arrays of a list of per-site pytrees.
+
+    ``per_site[i]`` (host arrays, the same shapes on every site) goes
+    straight to device i of ``mesh`` along ``axis``, and each leaf becomes
+    one ``(s, ...)`` array laid out ``P(axis)``: what a ``shard_map`` over
+    ``axis`` takes without moving a byte, and what a deployment holds,
+    where each site's payload already lives on its own chip.  Nothing is
+    stacked on the host or staged on one device."""
+    devices = list(mesh.devices.flat)
+    if len(per_site) != len(devices):
+        raise ValueError(f"{len(per_site)} site payloads for a mesh of "
+                         f"{len(devices)} devices")
+    sharding = NamedSharding(mesh, P(axis))
+
+    def put(*leaves):
+        blocks = [jax.device_put(np.expand_dims(a, 0), d)
+                  for a, d in zip(leaves, devices)]
+        return jax.make_array_from_single_device_arrays(
+            (len(blocks),) + np.shape(leaves[0]), sharding, blocks)
+
+    return jax.tree_util.tree_map(put, *per_site)
 
 
 def gather_sites(tree, axis: str = "sites"):

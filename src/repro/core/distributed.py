@@ -142,7 +142,8 @@ def distributed_cluster(
         cap = gids_h.shape[1]
         per_rec = [int((gids_h[i] >= 0).sum()) for i in range(s)]
         site_bytes = cap * (4 * d + 4 + 1 + 4)   # pts + w + valid + gid
-        obs.record_comm(per_rec, [site_bytes] * s, path="shard_map")
+        obs.record_comm(per_rec, [cap] * s, [site_bytes] * s,
+                        path="shard_map")
     return DistClusterResult(
         centers=centers,
         outlier_ids=out_ids,
@@ -214,8 +215,9 @@ def simulate_coordinator(
             all_cand.append(np.asarray(summ.is_candidate)[valid])
 
     # each site "sends" exactly its live summary records to the coordinator
+    sent = [p.shape[0] for p in all_pts]
     obs.record_comm(
-        [p.shape[0] for p in all_pts],
+        sent, sent,
         [p.nbytes + w.nbytes + g.nbytes + c.nbytes
          for p, w, g, c in zip(all_pts, all_w, all_gid, all_cand)],
         path="host-sim")

@@ -456,21 +456,25 @@ def snapshot() -> dict:
 
 
 def record_comm(per_site_records: Sequence[int],
+                per_site_rows: Sequence[int],
                 per_site_bytes: Sequence[int], **labels) -> None:
     """THE one communication-accounting mechanism.
 
     Every gather path — the sharded stream refresh, the host-simulated
-    coordinator, the shard_map one-shot — reports the same way: valid
-    records (the paper's communication measure, Chen/Sun/Zhang 1805.09495)
-    and padded payload bytes (what actually crosses the interconnect), per
-    site, accumulated into ``comm.records{site=i}`` / ``comm.bytes{site=i}``
-    counters plus a ``comm.rounds`` round counter.
+    coordinator, the shard_map one-shot — reports the same way, per site:
+    valid records (the paper's communication measure, Chen/Sun/Zhang
+    1805.09495), the rows the site puts on the exchange (its padded
+    payload, which every participant's replicated solve also runs over)
+    and their bytes (what actually crosses the interconnect), accumulated
+    into ``comm.records{site=i}`` / ``comm.rows{site=i}`` /
+    ``comm.bytes{site=i}`` counters plus a ``comm.rounds`` round counter.
     """
     reg = get_default_registry()
     if not reg.enabled:
         return
-    for site, (n_rec, n_bytes) in enumerate(zip(per_site_records,
-                                                per_site_bytes)):
+    for site, (n_rec, n_rows, n_bytes) in enumerate(zip(
+            per_site_records, per_site_rows, per_site_bytes)):
         reg.counter("comm.records", site=site, **labels).inc(int(n_rec))
+        reg.counter("comm.rows", site=site, **labels).inc(int(n_rows))
         reg.counter("comm.bytes", site=site, **labels).inc(int(n_bytes))
     reg.counter("comm.rounds", **labels).inc()

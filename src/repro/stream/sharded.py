@@ -27,11 +27,12 @@ Execution paths, same math:
   trees, the gather is a concatenation in site order — bit-identical to
   what the collective delivers — and communication is *accounted* (records
   and bytes) rather than performed;
-* ``use_shard_map=True``: the gather + second level run as one
-  ``shard_map`` program over the ``sites`` mesh axis
-  (``repro.core.collective``), so on hardware the root exchange lowers to
-  one ICI collective per leaf of the payload.  It needs >= s devices and
-  raises at construction otherwise — it never quietly runs host-sim.
+* ``use_shard_map=True``: each site's packed root is uploaded straight to
+  its own device of the ``sites`` mesh, and the gather + second level run
+  as one ``shard_map`` program over that axis (``repro.core.collective``),
+  so on hardware the root exchange lowers to one ICI collective per leaf
+  of the payload.  It needs >= s devices and raises at construction
+  otherwise — it never quietly runs host-sim.
 
 The read path (micro-batched scoring, latency accounting) and the
 double-buffered async refresh are inherited from
@@ -55,8 +56,8 @@ import numpy as np
 from repro import obs
 from repro.checkpoint.manager import CheckpointManager
 from repro.core.collective import (gather_sites, gathered_bytes,
-                                   payload_bytes, replicated_coordinator,
-                                   sites_mesh)
+                                   payload_bytes, put_sites,
+                                   replicated_coordinator, sites_mesh)
 from repro.core.distributed import local_budget
 from repro.stream.service import (BaseServiceConfig, ModelState,
                                   PendingFit, ServingFrontEnd, fit_model)
@@ -137,6 +138,8 @@ class ShardedStreamService(ServingFrontEnd):
         for i, tr in enumerate(self.trees):
             tr.obs_labels["site"] = i
         self._routed = 0             # round-robin cursor over sites
+        # one site per device (shard_map path): roots upload onto it
+        self._mesh = sites_mesh(cfg.n_sites) if cfg.use_shard_map else None
         self._fit_program = None     # cached shard_map program (all refreshes)
         self.last_refresh: Optional[RefreshStats] = None
 
@@ -188,7 +191,7 @@ class ShardedStreamService(ServingFrontEnd):
                                  policy=cfg.policy)
 
             self._fit_program = replicated_coordinator(
-                per_site, sites_mesh(cfg.n_sites), n_sharded=1)
+                per_site, self._mesh, n_sharded=1)
         return self._fit_program
 
     def _fit_closure(self, version: int):
@@ -225,22 +228,19 @@ class ShardedStreamService(ServingFrontEnd):
         for i, tr in enumerate(self.trees):
             with obs.trace("refresh.site_root", topology="sharded", site=i):
                 roots.append(tr.packed_root(rows))
-        pts = np.stack([r[0] for r in roots])          # (s, rows, d)
-        wts = np.stack([r[1] for r in roots])          # (s, rows)
-        val = np.stack([r[2] for r in roots])          # (s, rows)
-        one_site = (roots[0][0], roots[0][1], roots[0][2])
         use_sm = cfg.use_shard_map
-        site_bytes = payload_bytes(one_site)
+        site_bytes = payload_bytes(roots[0])
         self.last_refresh = RefreshStats(
             version=version,
             path="shard_map" if use_sm else "host-sim",
             root_rows=rows,
             per_site_records=tuple(recs),
             comm_records=int(sum(recs)),
-            comm_bytes=gathered_bytes(one_site, cfg.n_sites),
+            comm_bytes=gathered_bytes(roots[0], cfg.n_sites),
             payload_bytes=site_bytes)
         # every site ships the same padded root shape, hence equal bytes
-        obs.record_comm(recs, [site_bytes] * cfg.n_sites, topology="sharded")
+        obs.record_comm(recs, [rows] * cfg.n_sites,
+                        [site_bytes] * cfg.n_sites, topology="sharded")
         if store is not None:
             # epoch-keyed: the same roots refit to the same model.  The sum
             # is strictly monotone in the per-site epochs, so it collides
@@ -262,18 +262,17 @@ class ShardedStreamService(ServingFrontEnd):
         if not use_sm:
             # host-sim: concatenation in site order is exactly what the
             # collective would deliver to every participant
-            s, r, d = pts.shape
             return PendingFit(
-                (jnp.asarray(pts.reshape(s * r, d)),
-                 jnp.asarray(wts.reshape(s * r)),
-                 jnp.asarray(val.reshape(s * r))),
+                tuple(jnp.asarray(np.concatenate([r[j] for r in roots]))
+                      for j in range(3)),
                 functools.partial(
                     fit_model, key=key, version=version, k=cfg.k, t=cfg.t,
                     iters=cfg.second_iters, metric=cfg.metric,
                     policy=cfg.policy, init_centers=init))
 
         program = self._gathered_program()
-        triple = (jnp.asarray(pts), jnp.asarray(wts), jnp.asarray(val))
+        # each site's root straight onto its own chip, as in a deployment
+        triple = put_sites(roots, self._mesh)
         return PendingFit((triple,),
                           lambda tr: program(tr, key, np.int32(version)))
 

@@ -392,6 +392,74 @@ def test_sharded_comm_accounting_matches_refresh_stats():
         assert f"tree.records{{site=0,summarizer={summ}}}" in snap["gauges"]
 
 
+def _site_counts(counters: dict, name: str) -> dict:
+    """{site: value} of a per-site counter (one label set per site)."""
+    out = {}
+    for key, v in counters.items():
+        base, labels = split_key(key)
+        if base == name:
+            out[int(labels["site"])] = v
+    return out
+
+
+def test_comm_rows_is_the_padded_root_of_every_refresh():
+    """``comm.rows{site}`` grows by the packed-root rows each site puts on
+    the exchange, refresh by refresh, and never counts fewer than the
+    live records beside it."""
+    with obs.using_registry(obs.MetricsRegistry()) as reg:
+        cfg = ShardedServiceConfig(
+            dim=4, k=3, t=8, n_sites=3, leaf_size=1024, refresh_every=10**6,
+            micro_batch=32, second_iters=5, seed=0)
+        svc = ShardedStreamService(cfg)
+        data = _ingest_data(900)
+        seen_rows = set()
+        before = reg.snapshot()["counters"]
+        for lo in range(0, 900, 150):
+            svc.ingest(data[lo:lo + 150])
+            svc.refresh()
+            st = svc.last_refresh
+            after = reg.snapshot()["counters"]
+            rows1, rows0 = (_site_counts(after, "comm.rows"),
+                            _site_counts(before, "comm.rows"))
+            rec1, rec0 = (_site_counts(after, "comm.records"),
+                          _site_counts(before, "comm.records"))
+            for i in range(cfg.n_sites):
+                assert rows1[i] - rows0.get(i, 0) == st.root_rows
+                assert rec1[i] - rec0.get(i, 0) == st.per_site_records[i]
+                assert rec1[i] <= rows1[i]
+            seen_rows.add(st.root_rows)
+            before = after
+        assert len(seen_rows) >= 2        # the bucket grew along the way
+
+
+def test_comm_rows_on_the_one_shot_paths():
+    """The host-simulated coordinator sends exactly its live records; the
+    shard_map one-shot puts each site's whole fixed-shape summary on the
+    exchange."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.collective import sites_mesh
+    from repro.core.distributed import (distributed_cluster,
+                                        simulate_coordinator)
+    x, _ = gauss(n_centers=3, per_center=150, d=4, t=10, seed=0)
+    x = np.asarray(x, np.float32)
+    with obs.using_registry(obs.MetricsRegistry()) as reg:
+        simulate_coordinator([x[:225], x[225:]], jax.random.key(0), k=3,
+                             t=10, second_iters=5)
+        c = reg.snapshot()["counters"]
+        rows = _site_counts(c, "comm.rows")
+        assert rows == _site_counts(c, "comm.records") and len(rows) == 2
+        assert all(v > 0 for v in rows.values())
+    with obs.using_registry(obs.MetricsRegistry()) as reg:
+        res = distributed_cluster(jnp.asarray(x)[None], jax.random.key(0),
+                                  sites_mesh(1), k=3, t=10, second_iters=5)
+        c = reg.snapshot()["counters"]
+        cap = np.asarray(res.summary_ids).size
+        assert _site_counts(c, "comm.rows") == {0: cap}
+        assert _site_counts(c, "comm.records")[0] <= cap
+
+
 def test_scores_bit_identical_with_metrics_on_and_off():
     x = _ingest_data(600)
     q = _ingest_data(64, seed=7)
@@ -465,11 +533,13 @@ def test_session_stats_covers_every_topology(kind):
         assert any(k.startswith("kernels.dispatch{") for k in c)
         if kind == "oneshot":
             assert any(k.startswith("comm.records{") for k in c)
+            assert any(k.startswith("comm.rows{") for k in c)
             assert any(k.startswith("phase.oneshot.site_summary{")
                        for k in h)
         if kind == "sharded":
             assert c["comm.rounds{topology=sharded}"] >= 1
             assert any(k.startswith("comm.bytes{") for k in c)
+            assert any(k.startswith("comm.rows{") for k in c)
 
 
 def test_session_stats_is_json_and_prom_renderable():

@@ -153,44 +153,90 @@ _SHARD_MAP_EQ = textwrap.dedent("""
     os.environ["JAX_DEFAULT_PRNG_IMPL"] = "threefry2x32"
     import json
     import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.data.synthetic import gauss
     from repro.stream import ShardedServiceConfig, ShardedStreamService
 
     x, _ = gauss(n_centers=6, per_center=400, t=24, sigma=0.05, seed=10)
-    kw = dict(dim=5, k=6, t=24, n_sites=4, leaf_size=256,
-              refresh_every=1024, seed=10)
-    host = ShardedStreamService(ShardedServiceConfig(**kw))
-    coll = ShardedStreamService(ShardedServiceConfig(**kw,
-                                                     use_shard_map=True))
-    host.ingest(x); coll.ingest(x)
-    mh, mc = host.refresh(), coll.refresh()
-    print(json.dumps({
-        "paths": [host.last_refresh.path, coll.last_refresh.path],
-        "centers_equal": bool(np.array_equal(np.asarray(mh.centers),
-                                             np.asarray(mc.centers))),
-        "threshold_equal": float(mh.threshold) == float(mc.threshold),
-        "cost_equal": float(mh.cost) == float(mc.cost),
-        "one_device": all(len(a.sharding.device_set) == 1 for a in mc),
-        "comm_bytes": [host.last_refresh.comm_bytes,
-                       coll.last_refresh.comm_bytes]}))
+
+
+    # each leaf is (s, ...) over the sites mesh, and each device's block
+    # is its own site's packed root, resident there alone
+    def laid_out(svc, inputs):
+        rows = svc.last_refresh.root_rows
+        roots = [tr.packed_root(rows) for tr in svc.trees]
+        devices = list(svc._mesh.devices.flat)
+        ok = True
+        for j, a in enumerate(inputs):
+            ok &= (isinstance(a.sharding, NamedSharding)
+                   and a.sharding.spec == P("sites")
+                   and a.shape[0] == 4 and len(a.addressable_shards) == 4)
+            for sh in a.addressable_shards:
+                site = sh.index[0].start or 0
+                ok &= sh.device == devices[site]
+                ok &= sh.data.shape[0] == 1
+                ok &= len(sh.data.sharding.device_set) == 1
+                ok &= bool(np.array_equal(np.asarray(sh.data)[0],
+                                          roots[site][j]))
+        return bool(ok)
+
+
+    def compare(async_refresh):
+        kw = dict(dim=5, k=6, t=24, n_sites=4, leaf_size=256,
+                  refresh_every=1024, seed=10, async_refresh=async_refresh)
+        host = ShardedStreamService(ShardedServiceConfig(**kw))
+        coll = ShardedStreamService(ShardedServiceConfig(**kw,
+                                                         use_shard_map=True))
+        layouts = []
+        closure = coll._fit_closure
+
+        def fit_closure(version):
+            fit = closure(version)
+            layouts.append(laid_out(coll, fit.inputs[0]))
+            return fit
+
+        coll._fit_closure = fit_closure
+        host.ingest(x); coll.ingest(x)
+        mh, mc = host.refresh(), coll.refresh()
+        return {
+            "paths": [host.last_refresh.path, coll.last_refresh.path],
+            "versions": [int(mh.version), int(mc.version)],
+            "layouts": layouts,
+            "centers_equal": bool(np.array_equal(np.asarray(mh.centers),
+                                                 np.asarray(mc.centers))),
+            "threshold_equal": float(mh.threshold) == float(mc.threshold),
+            "cost_equal": float(mh.cost) == float(mc.cost),
+            "one_device": all(len(a.sharding.device_set) == 1 for a in mc),
+            "comm_bytes": [host.last_refresh.comm_bytes,
+                           coll.last_refresh.comm_bytes]}
+
+
+    print(json.dumps([compare(False), compare(True)]))
 """)
 
 
 @pytest.mark.slow
 def test_shard_map_refresh_bit_identical_to_host_sim_subprocess():
-    """Real 4-device shard_map gather == host-simulated gather, bitwise."""
+    """Real 4-device shard_map gather == host-simulated gather, bitwise,
+    with the refresh inline and on the worker thread.  Every shard_map
+    refresh takes its inputs laid out one site per device: each device's
+    block is that site's packed root, uploaded there and nowhere else."""
     env = dict(os.environ, PYTHONPATH="src")
     out = subprocess.run([sys.executable, "-c", _SHARD_MAP_EQ],
                          cwd=os.path.dirname(os.path.dirname(
                              os.path.abspath(__file__))),
                          env=env, capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
-    res = json.loads(out.stdout.strip().splitlines()[-1])
-    assert res["paths"] == ["host-sim", "shard_map"]
-    assert res["centers_equal"] and res["threshold_equal"] and res["cost_equal"]
-    # the gathered model lands on one device, ready for the score kernel
-    assert res["one_device"]
-    assert res["comm_bytes"][0] == res["comm_bytes"][1] > 0
+    for res in json.loads(out.stdout.strip().splitlines()[-1]):
+        assert res["paths"] == ["host-sim", "shard_map"]
+        assert res["versions"][0] == res["versions"][1] >= 2
+        # cadence refreshes during ingest and the final one: all laid out
+        assert len(res["layouts"]) >= 2 and all(res["layouts"])
+        assert (res["centers_equal"] and res["threshold_equal"]
+                and res["cost_equal"])
+        # the gathered model lands on one device, ready for the score kernel
+        assert res["one_device"]
+        assert res["comm_bytes"][0] == res["comm_bytes"][1] > 0
 
 
 def test_shard_map_with_too_few_devices_raises():
