@@ -590,7 +590,9 @@ class StreamService(ServingFrontEnd):
         super().__init__(cfg)
         key = key if key is not None else jax.random.key(cfg.seed)
         kt, self._model_key = jax.random.split(key)
-        self.tree = StreamTree(cfg.tree_config(), kt)
+        # the root stays on the chip between refreshes (StreamTree mirrors)
+        self.tree = StreamTree(cfg.tree_config(), kt,
+                               device=jax.devices()[0])
 
     def _root_records(self) -> int:
         return self.tree.num_records
@@ -603,6 +605,11 @@ class StreamService(ServingFrontEnd):
 
     def _fit_closure(self, version: int):
         """Snapshot the tree root now; fit later (possibly on a worker).
+
+        The root is assembled on the device from the tree's mirrors
+        (``StreamTree.device_root``): only nodes made since the last
+        refresh are uploaded, and the ``refresh.root_rows_*`` counters say
+        how many rows were already there.
 
         With ``cfg.store`` set, the fit key is derived from the tree's
         ``root_epoch`` instead of the model version: an unchanged root then
@@ -636,9 +643,13 @@ class StreamService(ServingFrontEnd):
             self._pending_fit_epoch = epoch
         else:
             key = jax.random.fold_in(self._model_key, version)
-        pts, wts, valid = self.tree.packed_root()
+        root, uploaded = self.tree.device_root()
+        obs.counter("refresh.root_rows_resident", topology=self._topology
+                    ).inc(self.tree.num_records - uploaded)
+        obs.counter("refresh.root_rows_uploaded",
+                    topology=self._topology).inc(uploaded)
         return PendingFit(
-            (jnp.asarray(pts), jnp.asarray(wts), jnp.asarray(valid)),
+            root,
             functools.partial(
                 fit_model, key=key, version=version, k=cfg.k, t=cfg.t,
                 iters=cfg.second_iters, metric=cfg.metric,
@@ -688,7 +699,8 @@ class StreamService(ServingFrontEnd):
                 f"checkpoint — restore it with the service that wrote it")
         svc = cls(cfg)
         state, _ = manager.restore(svc._skeleton(), step)
-        svc.tree = StreamTree.from_state(cfg.tree_config(), state["tree"])
+        svc.tree = StreamTree.from_state(cfg.tree_config(), state["tree"],
+                                         device=svc.tree.device)
         svc._since_refresh = int(state["counters"]["since_refresh"])
         svc._next_id = int(state["counters"]["next_id"])
         lfe = int(state["counters"]["last_fit_epoch"])

@@ -26,6 +26,13 @@ The tree also tracks a monotone ``root_epoch`` (bumped on every mutation
 that changes ``root()``) plus per-node creation epochs, which is what
 lets the serving layer skip or warm-start provably-redundant refreshes.
 
+Device mirrors (optional): a tree given a ``device`` keeps each node's
+summary on that device from the first refresh that needs it until the
+node is evicted, merged away or spilled — a summary never changes once
+made — and ``device_root()`` assembles the refresh root there, bit for
+bit ``packed_root()``: a refresh uploads only the nodes made since the
+last one.  Paged-in nodes and the leaf buffer are uploaded transiently.
+
 Checkpointing: the tree's state packs into a *fixed-shape* pytree of
 arrays (``pack_state``/``from_state``), so ``CheckpointManager`` can
 save/restore it across process restarts with its usual shape-checked
@@ -36,11 +43,13 @@ budget.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Optional, TYPE_CHECKING
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro import obs
 from repro.kernels.dispatch import KernelPolicy, get_default_policy
@@ -125,13 +134,44 @@ class TreeNode:
     nbytes: int = 0          # resident payload bytes of the summary
     weight: float = 0.0      # summary mass (WeightedSummary.total_weight)
     spill_step: Optional[int] = None   # store step id while spilled
+    # (points (b, d), weights (b,)) on the tree's device, zero-padded to
+    # b = _bucket(n_records); None until a refresh uploads the node, and
+    # again once it is evicted, merged away or spilled
+    mirror: Optional[tuple] = dataclasses.field(
+        default=None, repr=False, compare=False)
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _place(points, weights, m_points, m_weights, start, n):
+    """Write rows [0, n) of a zero-padded mirror at row ``start`` of the
+    root being assembled, in place.  The write covers the mirror's whole
+    span, moved left where it would run past the root's end and rolled to
+    match, and keeps what is there outside the node's n rows: one program
+    per (root rows, mirror span) pair, and no buffer beyond the root."""
+    span = m_points.shape[0]
+    at = jnp.minimum(start, points.shape[0] - span)
+    shift = start - at
+    i = jnp.arange(span)
+    keep = (i >= shift) & (i < shift + n)
+    p = jnp.where(keep[:, None], jnp.roll(m_points, shift, axis=0),
+                  lax.dynamic_slice_in_dim(points, at, span))
+    w = jnp.where(keep, jnp.roll(m_weights, shift),
+                  lax.dynamic_slice_in_dim(weights, at, span))
+    return (lax.dynamic_update_slice_in_dim(points, p, at, 0),
+            lax.dynamic_update_slice_in_dim(weights, w, at, 0))
 
 
 class StreamTree:
-    """Mergeable summary tree; all state numpy-side, distance loops jitted."""
+    """Mergeable summary tree; state numpy-side, distance loops jitted.
 
-    def __init__(self, cfg: TreeConfig, key: jax.Array | None = None):
+    ``device``: where ``device_root`` keeps the nodes' mirrors; None (the
+    default) mirrors nothing."""
+
+    def __init__(self, cfg: TreeConfig, key: jax.Array | None = None, *,
+                 device: Optional[jax.Device] = None):
         self.cfg = cfg
+        self.device = device
+        self._placed_rows: set[int] = set()  # root sizes device_root warmed
         self.key = key if key is not None else jax.random.key(cfg.seed)
         self.nodes: List[TreeNode] = []      # chronological order
         self._buf = np.zeros((cfg.leaf_size, cfg.dim), np.float32)
@@ -240,6 +280,9 @@ class StreamTree:
     def _enforce_store(self) -> None:
         if self.cfg.store is not None and self.cfg.store.tiered:
             self.store.enforce(self.nodes)
+            for nd in self.nodes:
+                if nd.summary is None:   # spilled: off the device as well
+                    nd.mirror = None
 
     def _node_summary(self, nd: TreeNode) -> WeightedSummary:
         """The node's summary, demand-paged from the spill tier if cold
@@ -249,6 +292,7 @@ class StreamTree:
         return self._store.page_in(nd)
 
     def _discard_node(self, nd: TreeNode) -> None:
+        nd.mirror = None
         if nd.spill_step is not None:
             self._store.discard(nd)
 
@@ -391,6 +435,70 @@ class StreamTree:
         out_v[:s] = True
         return out_p, out_w, out_v
 
+    def device_root(self) -> tuple[tuple[jax.Array, jax.Array, jax.Array],
+                                   int]:
+        """``packed_root()`` assembled on ``self.device`` from the nodes'
+        mirrors: the same rows in the same order, bit for bit.
+
+        A node without a mirror is uploaded here, and its mirror kept while
+        its summary is resident; a paged-in node and the leaf buffer are
+        uploaded for this call only.  Returns ``((points, weights, valid),
+        live rows uploaded)``.  The assembly is one placement per node into
+        a zeroed root; the placements for every mirror span the tree can
+        make are compiled the first time it meets that root size.
+        """
+        if self.device is None:
+            raise RuntimeError("device_root() on a tree given no device")
+        d, s = self.cfg.dim, self.num_records
+        rows = _bucket(max(s, 1))
+        points = jnp.zeros((rows, d), jnp.float32, device=self.device)
+        weights = jnp.zeros((rows,), jnp.float32, device=self.device)
+        if rows not in self._placed_rows:
+            self._placed_rows.add(rows)
+            # a node holds at most _cap records, the buffer < leaf_size
+            top = min(rows, _bucket(max(self._cap, self.cfg.leaf_size)))
+            span = _bucket(1)
+            while span <= top:
+                points, weights = _place(
+                    points, weights,
+                    jnp.zeros((span, d), jnp.float32, device=self.device),
+                    jnp.zeros((span,), jnp.float32, device=self.device),
+                    0, 0)
+                span <<= 1
+        start = uploaded = 0
+        for nd in self.nodes:
+            mirror = nd.mirror
+            if mirror is None:
+                summ = self._node_summary(nd)
+                mirror = self._upload(summ.points, summ.weights)
+                uploaded += nd.n_records
+                if nd.summary is not None:   # a page-in stays transient
+                    nd.mirror = mirror
+            points, weights = _place(points, weights, *mirror, start,
+                                     nd.n_records)
+            start += nd.n_records
+        if self._buf_n:   # copied: later ingest overwrites the buffer
+            n = self._buf_n
+            points, weights = _place(
+                points, weights, *self._upload(self._buf[:n].copy(),
+                                               self._buf_w[:n].copy()),
+                start, n)
+            uploaded += n
+        valid = jnp.arange(rows, device=self.device) < s
+        return (points, weights, valid), uploaded
+
+    def _upload(self, points: np.ndarray, weights: np.ndarray):
+        """(points, weights) zero-padded to ``_bucket`` rows, on the device.
+        Arrays already that long are sent as they are: callers hand over
+        arrays that nothing overwrites."""
+        pad = _bucket(points.shape[0]) - points.shape[0]
+        if pad:
+            points = np.concatenate(
+                [points, np.zeros((pad, self.cfg.dim), np.float32)])
+            weights = np.concatenate([weights, np.zeros((pad,), np.float32)])
+        return (jax.device_put(np.asarray(points, np.float32), self.device),
+                jax.device_put(np.asarray(weights, np.float32), self.device))
+
     @property
     def total_weight(self) -> float:
         _, w, _ = self.root()
@@ -445,8 +553,10 @@ class StreamTree:
         return cls(cfg).pack_state()
 
     @classmethod
-    def from_state(cls, cfg: TreeConfig, state: dict) -> "StreamTree":
-        tree = cls(cfg)
+    def from_state(cls, cfg: TreeConfig, state: dict, *,
+                   device: Optional[jax.Device] = None) -> "StreamTree":
+        """The tree ``pack_state`` packed; it starts with no mirrors."""
+        tree = cls(cfg, device=device)
         g = {k: np.asarray(v) for k, v in state.items()}
         tree.key = jax.random.wrap_key_data(
             jnp.asarray(g["key_data"], jnp.uint32))
